@@ -13,6 +13,8 @@ import os
 import sys
 import time
 
+from repro import compile_cache
+
 
 def main() -> None:
     quick = "--full" not in sys.argv
@@ -43,4 +45,5 @@ def main() -> None:
 
 
 if __name__ == '__main__':
+    compile_cache.enable()
     main()

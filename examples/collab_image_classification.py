@@ -9,7 +9,7 @@ import os
 
 import jax
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.checkpoint import save_pytree
 from repro.core import client as client_lib, collab, vec_collab
 from repro.data import partition, synthetic
@@ -131,4 +131,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

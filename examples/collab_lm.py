@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs, sim
+from repro import compile_cache, obs, sim
 from repro.configs import get_arch
 from repro.core import losses, prototypes
 from repro.data import synthetic
@@ -205,4 +205,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

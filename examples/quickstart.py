@@ -8,6 +8,7 @@ prints per-round accuracy plus the exact communication ledger.
 """
 import jax
 
+from repro import compile_cache
 from repro.core import client as client_lib, collab
 from repro.data import partition, synthetic
 from repro.models import cnn
@@ -43,4 +44,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_arch
 from repro.data import synthetic
 from repro.launch import serve as serve_lib
@@ -92,4 +93,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

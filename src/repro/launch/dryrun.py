@@ -26,6 +26,9 @@ from repro.types import CollabConfig
 
 ARTDIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                       "artifacts", "dryrun")
+# The chip the roofline terms model (a `roofline.PEAKS` key): the dry-run
+# compiles on placeholder host devices, so the chip is named, not detected.
+CHIP = "TPU v5 lite"
 
 
 def should_skip(cfg, shape) -> str:
@@ -160,12 +163,12 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool,
 
     terms = roofline.terms({"flops": corrected["flops"],
                             "bytes accessed": corrected["bytes"]},
-                           {"total": corrected["coll"]})
+                           {"total": corrected["coll"]}, CHIP)
     mf = roofline.model_flops(cfg, shape, n_clients)
     n_dev = mesh.size
     hlo_flops_global = terms["flops"] * n_dev
     rec.update(
-        status="ok", n_devices=n_dev,
+        status="ok", n_devices=n_dev, chip=CHIP,
         lower_s=round(t_lower, 2), compile_s=round(t_compile, 2),
         raw_scan_metrics=raw, probes=probes,
         memory=mem_rec, terms=terms,

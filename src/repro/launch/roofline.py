@@ -1,8 +1,11 @@
 """Roofline terms from a compiled dry-run artifact (no hardware needed).
 
-  compute_s    = HLO_FLOPs_per_device / peak_FLOPs_chip     (197 TF/s bf16, v5e)
-  memory_s     = HLO_bytes_per_device / HBM_bw              (819 GB/s)
-  collective_s = collective_bytes_per_device / link_bw      (~50 GB/s ICI)
+  compute_s    = HLO_FLOPs_per_device / peak_FLOPs_chip
+  memory_s     = HLO_bytes_per_device / HBM_bw
+  collective_s = collective_bytes_per_device / link_bw
+
+The peaks come from `PEAKS`, keyed by the `device_kind` JAX reports for the
+chip being modelled; a kind missing from the table is an error.
 
 cost_analysis() reports the per-device (post-SPMD-partition) program, so no
 further division by chip count is needed. collective_bytes is parsed from the
@@ -27,9 +30,21 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-PEAK_FLOPS = 197e12        # bf16 per chip, TPU v5e
-HBM_BW = 819e9             # bytes/s per chip
-LINK_BW = 50e9             # bytes/s per ICI link
+# Per-chip peaks by `jax.Device.device_kind`. Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of
+# chip-to-chip interconnect over 4 ICI links (50 GB/s per link).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Published peaks of one chip of `device_kind`; unknown kinds raise."""
+    if device_kind not in PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add it to "
+            f"roofline.PEAKS with its source (known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -87,16 +102,18 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
     return out
 
 
-def terms(cost: Optional[dict], coll: Dict[str, int]) -> Dict[str, float]:
+def terms(cost: Optional[dict], coll: Dict[str, int],
+          device_kind: str) -> Dict[str, float]:
+    pk = peaks(device_kind)
     flops = float((cost or {}).get("flops", 0.0))
     byts = float((cost or {}).get("bytes accessed", 0.0))
     t = {
         "flops": flops,
         "bytes": byts,
         "collective_bytes": float(coll.get("total", 0)),
-        "compute_s": flops / PEAK_FLOPS,
-        "memory_s": byts / HBM_BW,
-        "collective_s": float(coll.get("total", 0)) / LINK_BW,
+        "compute_s": flops / pk["flops"],
+        "memory_s": byts / pk["hbm_bw"],
+        "collective_s": float(coll.get("total", 0)) / pk["link_bw"],
     }
     dom = max(("compute_s", "memory_s", "collective_s"), key=lambda k: t[k])
     t["bottleneck"] = dom.replace("_s", "")

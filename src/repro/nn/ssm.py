@@ -79,7 +79,9 @@ def ssd_chunked(xh, Bm, Cm, dt, A, Q: int, h0=None):
     Gm = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)               # (B,nc,Q,Q)
     Ld = cums[:, :, :, None, :] - cums[:, :, None, :, :]     # (B,nc,Q,Q,H) i,j
     causal = jnp.tril(jnp.ones((Q, Q), bool))
-    L = jnp.where(causal[None, None, :, :, None], jnp.exp(Ld), 0.0)
+    # mask before the exp: above the diagonal exp(Ld) overflows on long
+    # chunks, and 0 * inf in the where()'s gradient would be NaN
+    L = jnp.exp(jnp.where(causal[None, None, :, :, None], Ld, -jnp.inf))
     W = Gm[..., None] * L * dtc[:, :, None, :, :]            # weight (i,j,h)
     y_intra = jnp.einsum("bcijh,bcjhp->bcihp", W, xh)
 

@@ -75,7 +75,10 @@ def mlstm_chunked(q, k, v, logf, logi, Q: int, state=None):
     Gm = jnp.einsum("bcihp,bcjhp->bcijh", qc, kc)            # (B,nc,Q,Q,H)
     Ld = cums[:, :, :, None, :] - cums[:, :, None, :, :] + ic[:, :, None, :, :]
     causal = jnp.tril(jnp.ones((Q, Q), bool))
-    W = jnp.where(causal[None, None, :, :, None], Gm * jnp.exp(Ld), 0.0)
+    # Mask before the exp: above the diagonal Ld grows with the chunk
+    # (~0.7 per step at f = sigmoid(0)) and exp overflows past Q ~ 128; a
+    # where() after the exp then sends 0 * inf = NaN into the gradient.
+    W = Gm * jnp.exp(jnp.where(causal[None, None, :, :, None], Ld, -jnp.inf))
     y_intra = jnp.einsum("bcijh,bcjhp->bcihp", W, vc)
 
     decay_to_end = jnp.exp(total[:, :, None, :] - cums + ic)  # (B,nc,Q,H)

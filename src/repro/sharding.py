@@ -14,16 +14,17 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-# Version-compat shard_map: `jax.shard_map` is the stable name on newer jax;
-# older releases only ship it under jax.experimental. Import it from here
-# (tests and core/vec_collab.py do) so the rest of the codebase is
-# version-agnostic.
-try:
-    shard_map = jax.shard_map
-except AttributeError:                              # jax < 0.6
-    from jax.experimental.shard_map import shard_map  # type: ignore
+
+def auto_mesh(shape: Sequence[int], names: Sequence[str]) -> Mesh:
+    """`jax.make_mesh` with every axis `AxisType.Auto`. The placement API
+    and the launch shardings are written for GSPMD (jit in/out shardings
+    plus `with_sharding_constraint`); jax >= 0.7 defaults new meshes to
+    `Explicit` axes, under which `vmap` refuses inputs whose mapped axis is
+    sharded on some arguments and replicated on others."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def client_mesh(n_devices: Optional[int] = None) -> Mesh:
@@ -31,7 +32,7 @@ def client_mesh(n_devices: Optional[int] = None) -> Mesh:
     engine (vec_collab.py): the stacked client axis is sharded over it and
     the prototype merge becomes a psum. Defaults to all local devices."""
     n = n_devices or len(jax.devices())
-    return jax.make_mesh((n,), ("clients",))
+    return auto_mesh((n,), ("clients",))
 
 
 def axis_size(mesh: Mesh, name: str) -> int:

@@ -69,7 +69,7 @@ def test_psum_merge_single_device():
                                jnp.ones((4, 3)), jnp.zeros(4, jnp.int32))
     def f(s):
         return prototypes.psum_merge(s, "i")
-    out = sharding.shard_map(f, mesh=jax.make_mesh((1,), ("i",)),
-                             in_specs=jax.sharding.PartitionSpec(),
-                             out_specs=jax.sharding.PartitionSpec())(st)
+    out = jax.shard_map(f, mesh=sharding.auto_mesh((1,), ("i",)),
+                        in_specs=jax.sharding.PartitionSpec(),
+                        out_specs=jax.sharding.PartitionSpec())(st)
     np.testing.assert_allclose(out.sum, st.sum)

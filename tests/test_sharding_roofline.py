@@ -71,16 +71,27 @@ def test_collective_bytes_parser():
                                 "all-to-all", "collective-permute"))
 
 
+V5E = "TPU v5 lite"
+
+
 def test_terms_bottleneck_identification():
     t = roofline.terms({"flops": 197e12, "bytes accessed": 1.0},
-                       {"total": 0})
+                       {"total": 0}, V5E)
     assert t["bottleneck"] == "compute"
+    assert t["compute_s"] == pytest.approx(1.0)
     t = roofline.terms({"flops": 1.0, "bytes accessed": 819e9 * 2},
-                       {"total": 0})
+                       {"total": 0}, V5E)
     assert t["bottleneck"] == "memory"
     t = roofline.terms({"flops": 0.0, "bytes accessed": 0.0},
-                       {"total": 50e9 * 3})
+                       {"total": 50e9 * 3}, V5E)
     assert t["bottleneck"] == "collective"
+
+
+def test_peaks_unknown_device_kind_raises():
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks("cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.terms({"flops": 1.0}, {"total": 0}, "TPU v4")
 
 
 def test_model_flops_train_vs_decode():

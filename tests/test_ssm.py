@@ -57,6 +57,24 @@ def test_ssd_chunk_size_invariance():
     np.testing.assert_allclose(y8, y32, atol=1e-4, rtol=1e-4)
 
 
+def test_ssd_chunked_grad_finite_on_long_chunks():
+    # a 256-step chunk puts the decay exponent above the diagonal past
+    # exp's f32 range, which must not leak NaN into the gradient
+    B, S, H, P, N = 1, 512, 2, 4, 4
+    ks = jax.random.split(KEY, 4)
+    xh = jax.random.normal(ks[0], (B, S, H, P))
+    Bm = jax.random.normal(ks[1], (B, S, N))
+    Cm = jax.random.normal(ks[2], (B, S, N))
+    dt = jax.nn.softplus(jax.random.normal(ks[3], (B, S, H)))
+    A = -jnp.ones((H,))
+
+    def loss(*a):
+        return jnp.sum(ssm.ssd_chunked(*a, A, 256)[0])
+
+    grads = jax.grad(loss, argnums=range(4))(xh, Bm, Cm, dt)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in grads)
+
+
 def test_mamba2_decode_continues_prefill():
     cfg = get_arch("zamba2-1.2b").reduced(num_layers=1, d_model=64)
     cfg = dataclasses.replace(cfg, shared_attn_period=0,

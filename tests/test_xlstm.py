@@ -43,6 +43,25 @@ def test_mlstm_chunked_matches_naive(Q):
     np.testing.assert_allclose(y, want, atol=1e-4, rtol=1e-4)
 
 
+def test_mlstm_chunked_grad_finite_on_long_chunks():
+    # xlstm-125m's chunk (256): the decay exponent above the diagonal
+    # passes exp's f32 range, which must not leak NaN into the gradient
+    B, S, H, P = 1, 512, 2, 8
+    ks = jax.random.split(KEY, 5)
+    q, k, v = (jax.random.normal(ks[i], (B, S, H, P)) for i in range(3))
+    fpre = jax.random.normal(ks[3], (B, S, H))
+    logi = jax.random.normal(ks[4], (B, S, H))
+
+    def loss(*a):
+        q, k, v, fpre, logi = a
+        y, _ = xlstm.mlstm_chunked(q, k, v, jax.nn.log_sigmoid(fpre), logi,
+                                   256)
+        return jnp.sum(y)
+
+    grads = jax.grad(loss, argnums=range(5))(q, k, v, fpre, logi)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in grads)
+
+
 def test_mlstm_block_decode_continues_prefill():
     cfg = get_arch("xlstm-125m").reduced(num_layers=1, d_model=64)
     p = xlstm.init_mlstm(KEY, cfg, jnp.float32)
