@@ -1,0 +1,8 @@
+"""Training tokens over the whole window, per second of window and per
+chip (host clock)."""
+
+
+def read(run):
+    if run.cell.unit != "tokens" or run.window_s <= 0:
+        return None
+    return run.work / run.window_s / run.chips
