@@ -323,7 +323,7 @@ def gate_probe(name: str, floor_path: str) -> int:
 
 def _measure_telemetry(cfg, jsonl_path: str, trace_path: str) -> tuple:
     """(t_off, t_on): vec per-round seconds with telemetry fully off vs
-    fully ON (in-jit metrics + JSONL sink + trace recorder — the whole
+    fully ON (in-jit metrics + JSONL sink + span store — the whole
     opt-in surface, which also leaves the gate's artifacts behind for CI
     upload). Best of `reps` interleaved pairs, ALTERNATING which side of
     the pair runs first: machine drift within a pair (thermal, page
@@ -332,15 +332,18 @@ def _measure_telemetry(cfg, jsonl_path: str, trace_path: str) -> tuple:
     order as the real overhead this gate bounds."""
     from repro import obs
     kw = dict(per_client=cfg["per_client"])
-    on_cfg = obs.TelemetryConfig(jsonl=jsonl_path, trace=trace_path)
+    on_cfg = obs.TelemetryConfig(jsonl=jsonl_path)
     t_off = t_on = float("inf")
     for rep in range(int(cfg.get("reps", 4))):
         order = [(None, False), (on_cfg, True)]
         if rep % 2:
             order.reverse()
         for telem, is_on in order:
-            t = bench(cfg["n_clients"], "vec", cfg["model"], cfg["rounds"],
-                      telemetry=telem, **kw)
+            # the engines record spans inside `run()` alone, and the
+            # timing drives `run_round`: the on side records around it
+            with obs.trace.record(trace_path if is_on else None):
+                t = bench(cfg["n_clients"], "vec", cfg["model"],
+                          cfg["rounds"], telemetry=telem, **kw)
             if is_on:
                 t_on = min(t_on, t)
             else:

@@ -134,12 +134,6 @@ class CollabTrainer:
                           if self._telem else None)
         self._sink = (obs.JsonlWriter(self.telemetry.jsonl)
                       if self.telemetry and self.telemetry.jsonl else None)
-        self._tracer = (obs.TraceRecorder(path=self.telemetry.trace,
-                                          profile=self.telemetry.profile)
-                        if self.telemetry and (self.telemetry.trace
-                                               or self.telemetry.profile)
-                        else None)
-        self._span = self._tracer.span if self._tracer else obs.null_span
         self.clock = sim.get_clock(fleet.clock, seed=seed)
         self._queue = events.HostEventQueue()
         self.policy = relay_lib.get_policy(fleet.policy)
@@ -217,6 +211,10 @@ class CollabTrainer:
 
     # ------------------------------------------------------------------
     def run_round(self) -> Dict:
+        with obs.trace.span("run_round", round=len(self.history)):
+            return self._run_round()
+
+    def _run_round(self) -> Dict:
         ccfg = self.ccfg
         mode = ccfg.mode
         N = len(self.clients)
@@ -234,11 +232,10 @@ class CollabTrainer:
             mask = view.mask.copy()
             ext_ids = view.seat_ids
             if view.evicted.size:
-                with self._span("evict", round=r) as sp:
+                with obs.trace.span("evict"):
                     self.server.state = self.policy.evict_owners(
                         self.server.state,
                         jnp.asarray(view.evicted, jnp.int32))
-                    sp.block(self.server.state)
         else:
             mask = np.asarray(self.schedule.mask(r, N), bool)
             ext_ids = None
@@ -257,26 +254,24 @@ class CollabTrainer:
               else np.zeros((N,), np.int64))
         prev_state = self.server.state
         teachers: Dict[int, Dict] = {}
-        with self._span("teacher_read", round=r) as sp:
+        with obs.trace.span("teacher_read"):
             for i in present:
                 teachers[i] = (self.server.relay(
                     owner_of(i), max(1, ccfg.m_down), relay_ks[i],
                     state=self._snapshot(int(dl[i])))
                     if mode in ("cors", "fd")
                     else client_lib.empty_teacher(ccfg))
-            sp.block(teachers)
 
         # phase 2 — local updates (Algorithm 2); absent clients are frozen
         metrics_all = [jax.tree.map(float, client_lib.zero_metrics(ccfg))
                        for _ in range(N)]
-        with self._span("update", round=r) as sp:
+        with obs.trace.span("update"):
             for i in present:
                 c = self.clients[i]
                 c.params, c.opt_state, m = self._updaters[i](
                     c.params, c.opt_state, self._batches(c), teachers[i],
                     upd_ks[i])
                 metrics_all[i] = jax.tree.map(float, m)
-            sp.block([c.params for c in self.clients])
 
         # phase 3 — uplink + server merge (Algorithm 1). Present clients'
         # fresh uploads enter the event queue with their clock-model commit
@@ -294,7 +289,7 @@ class CollabTrainer:
             order_owners = [owner_of(i) for i in self._upload_order]
             birth_stamps = self.policy.host_stamps(self.server.state,
                                                    order_owners)
-            with self._span("upload", round=r):
+            with obs.trace.span("upload"):
                 for pos, i in enumerate(self._upload_order):
                     if not mask[i]:
                         continue
@@ -305,7 +300,7 @@ class CollabTrainer:
                                      stamp=int(birth_stamps[pos]),
                                      payload=payload,
                                      delay=int(delays[i]))
-            with self._span("commit", round=r) as sp:
+            with obs.trace.span("commit"):
                 due = self._queue.pop_due(r)
                 self.server.begin_round()
                 for birth, pos, cid, stamp, payload, _ in due:
@@ -315,7 +310,6 @@ class CollabTrainer:
                     self.server.upload(owner_of(cid), payload, stamp=stamp)
                 if due:
                     self.server.end_round()
-                sp.block(self.server.state)
             commits = [(birth, cid) for birth, pos, cid, *_ in due]
 
         if mode == "fedavg" and len(present):
@@ -341,7 +335,7 @@ class CollabTrainer:
                         if mode == "fedavg" else 0))
         self.ledger.log_round(up, down)
 
-        with self._span("eval", round=r):
+        with obs.trace.span("eval"):
             accs = [self.evaluate(c) for c in self.clients]
         rec = {"round": len(self.history) + 1,
                "acc_mean": float(np.mean(accs)),
@@ -375,16 +369,16 @@ class CollabTrainer:
         self.history.append(rec)
         if self._sink is not None:
             self._sink.write(rec)
-        if self._tracer is not None and self.telemetry.trace:
-            self._tracer.write()
         return rec
 
     def run(self, rounds: int, log_every: int = 0) -> List[Dict]:
-        for r in range(rounds):
-            rec = self.run_round()
-            if log_every and (r + 1) % log_every == 0:
-                print(f"  round {rec['round']:3d} acc {rec['acc_mean']:.4f}"
-                      f" ±{rec['acc_std']:.4f}")
+        with obs.trace.record(self.telemetry.trace if self.telemetry
+                              else None):
+            for r in range(rounds):
+                rec = self.run_round()
+                if log_every and (r + 1) % log_every == 0:
+                    print(f"  round {rec['round']:3d} acc "
+                          f"{rec['acc_mean']:.4f} ±{rec['acc_std']:.4f}")
         return self.history
 
     # ------------------------------------------------------------------

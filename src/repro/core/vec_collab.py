@@ -330,6 +330,7 @@ def make_async_round_step(spec: client_lib.ClientSpec, ccfg: CollabConfig,
 
     def step(params, opt, rstate, pending, batches, data_x, data_y, ids,
              relay_ks, upd_ks, upl_ks, mask, delays, round_idx, *lag):
+        obs.trace.count("traces")     # the body runs only when jax traces
         rstate0, pending0 = rstate, pending
         # phases 1-2 — downlink from the COMMITTED state of the client's
         # last sync (round start, or dl[i] rounds earlier under download
@@ -441,6 +442,7 @@ def make_bucket_update_step(spec: client_lib.ClientSpec, ccfg: CollabConfig,
 
     def step(params, opt, rstate, batches, data_x, data_y, ids,
              relay_ks, upd_ks, upl_ks, mask, *lag):
+        obs.trace.count("traces")     # the body runs only when jax traces
         teacher = (teachers(rstate, ids, relay_ks, lag[0]) if lagged
                    else teachers(rstate, ids, relay_ks))
         new_p, new_o, metrics = jax.vmap(local_update)(
@@ -472,6 +474,7 @@ def make_eval_hits(spec: client_lib.ClientSpec):
     argmax ops, no host sync per chunk)."""
 
     def hits(P, x, y):
+        obs.trace.count("traces")     # the body runs only when jax traces
         lg = jax.vmap(lambda p: spec.apply(p, x)[1])(P)
         return jnp.sum(jnp.argmax(lg, -1) == y[None], axis=-1)
 
@@ -529,17 +532,11 @@ class VectorizedCollabTrainer:
                               download_clock=download_clock)
         # Observability (repro.obs): in-jit RoundTelemetry is a STATIC
         # build flag on the round steps (off -> traced program unchanged);
-        # sinks/tracing are host-side round-record plumbing.
+        # the sink and the span store are host-side.
         self.telemetry = obs.resolve(telemetry)
         self._telem = self.telemetry is not None and self.telemetry.metrics
         self._sink = (obs.JsonlWriter(self.telemetry.jsonl)
                       if self.telemetry and self.telemetry.jsonl else None)
-        self._tracer = (obs.TraceRecorder(path=self.telemetry.trace,
-                                          profile=self.telemetry.profile)
-                        if self.telemetry and (self.telemetry.trace
-                                               or self.telemetry.profile)
-                        else None)
-        self._span = self._tracer.span if self._tracer else obs.null_span
         if isinstance(specs, client_lib.ClientSpec):
             specs = [specs] * len(params_list)
         assert len(specs) == len(params_list) == len(client_data)
@@ -808,6 +805,7 @@ class VectorizedCollabTrainer:
             # traced. The body is mesh-free — with a mesh, the SAME trace
             # is jitted under the placement-resolved shardings below and
             # GSPMD inserts the collectives at the exchange.
+            obs.trace.count("traces")  # the body runs only when jax traces
             hist, dl = lag if lagged else (None, None)
             rstate0 = rstate
             # phase 0 — participant gather: the round runs on the
@@ -953,125 +951,136 @@ class VectorizedCollabTrainer:
         return [(r, int(i)) for i in self._upload_order if mask_np[i]]
 
     def run_round(self) -> Dict:
-        if self.hetero:
-            return self._run_round_bucketed()
+        """One round. With the span store on (`obs.trace.start()`), its
+        spans are `run_round` > `inputs`, `round_step` (the bucketed
+        engine: `bucket_steps`, `commit`), `fetch`, `records`, `eval` >
+        `eval_fetch`, `log`; the counters `host_syncs` (arrays pulled to
+        the host) and `traces` land on `run_round`'s args."""
+        r = len(self.history)
+        with obs.trace.span("run_round", round=r):
+            if self.hetero:
+                return self._run_round_bucketed(r)
+            return self._run_round_fused(r)
+
+    def _run_round_fused(self, r: int) -> Dict:
         ccfg, N = self.ccfg, self.n_clients
         mode = ccfg.mode
-        r = len(self.history)
-        # Same key schedule as the sequential oracle: keys for ALL N
-        # clients regardless of participation (absent clients just never
-        # consume theirs), so seq and vec stay equivalence-testable under
-        # every schedule.
-        self.key, relay_ks, upd_ks, upl_ks = collab.round_keys(self.key, N)
-        if self._streaming:
-            # Cohort view: mask over SEATS, external ids per seat (the
-            # traced `ids` arg — seat turnover never retraces). LRU-evicted
-            # owners' ring slots are invalidated BEFORE any read this
-            # round, same order as the sequential oracle.
-            view = self._cohort.round(r)
-            mask_np = view.mask.copy()
-            ids = jnp.asarray(view.seat_ids, jnp.int32)
-            if view.evicted.size:
-                with self._span("evict", round=r) as sp:
-                    self.relay_state = self._evict(
-                        self.relay_state,
-                        jnp.asarray(view.evicted, jnp.int32))
-                    sp.block(self.relay_state)
-        else:
-            mask_np = np.asarray(self.schedule.mask(r, N), bool)
-            ids = jnp.arange(N, dtype=jnp.int32)
-        present = np.nonzero(mask_np)[0]
-        delays_np = (self.clock.delays(r, N) if self.clock is not None
-                     else np.zeros((N,), np.int64))
-        commits = self._round_commits(r, mask_np, delays_np)
-        mask = jnp.asarray(mask_np)
-        # Download lag: this round's (N,) snapshot ages, traced like the
-        # upload delays — the lag pattern never retraces the step.
-        lag = ((self.hist,
-                jnp.asarray(self.dl_clock.delays(r, N), jnp.int32))
-               if self._lagged else ())
+        with obs.trace.span("inputs"):
+            # Same key schedule as the sequential oracle: keys for ALL N
+            # clients regardless of participation (absent clients just
+            # never consume theirs), so seq and vec stay
+            # equivalence-testable under every schedule.
+            self.key, relay_ks, upd_ks, upl_ks = collab.round_keys(self.key,
+                                                                   N)
+            if self._streaming:
+                # Cohort view: mask over SEATS, external ids per seat (the
+                # traced `ids` arg — seat turnover never retraces).
+                # LRU-evicted owners' ring slots are invalidated BEFORE any
+                # read this round, same order as the sequential oracle.
+                view = self._cohort.round(r)
+                mask_np = view.mask.copy()
+                ids = jnp.asarray(view.seat_ids, jnp.int32)
+                if view.evicted.size:
+                    with obs.trace.span("evict"):
+                        self.relay_state = self._evict(
+                            self.relay_state,
+                            jnp.asarray(view.evicted, jnp.int32))
+            else:
+                mask_np = np.asarray(self.schedule.mask(r, N), bool)
+                ids = jnp.arange(N, dtype=jnp.int32)
+            present = np.nonzero(mask_np)[0]
+            delays_np = (self.clock.delays(r, N) if self.clock is not None
+                         else np.zeros((N,), np.int64))
+            commits = self._round_commits(r, mask_np, delays_np)
+            mask = jnp.asarray(mask_np)
+            # Download lag: this round's (N,) snapshot ages, traced like
+            # the upload delays — the lag pattern never retraces the step.
+            lag = ((self.hist,
+                    jnp.asarray(self.dl_clock.delays(r, N), jnp.int32))
+                   if self._lagged else ())
+            if self._async:
+                # Full-width async step: round_idx/delays are traced, so
+                # the event timeline never retraces; the pending buffer
+                # threads through like the relay state.
+                args = (self.params, self.opt_state, self.relay_state,
+                        self.pending, self.batches, self.data_x,
+                        self.data_y, ids, relay_ks, upd_ks, upl_ks, mask,
+                        jnp.asarray(delays_np, jnp.int32),
+                        jnp.asarray(r, jnp.int32), *lag)
+            else:
+                if self._k_active < N:
+                    idx_np = present             # static-k compaction
+                    assert idx_np.size == self._k_active, (
+                        "schedule emitted a mask inconsistent with its "
+                        "fixed_k", idx_np.size, self._k_active)
+                else:
+                    idx_np = np.arange(N)
+                args = (self.params, self.opt_state, self.relay_state,
+                        self.batches, self.data_x, self.data_y,
+                        ids, relay_ks, upd_ks, upl_ks, mask,
+                        jnp.asarray(idx_np, jnp.int32), *lag)
+        with obs.trace.span("round_step"):
+            out = self._round_step(*args)
         telem = None
-        if self._async:
-            # Full-width async step: round_idx/delays are traced, so the
-            # event timeline never retraces; the pending buffer threads
-            # through like the relay state.
-            with self._span("round_step", round=r) as sp:
-                out = sp.block(self._round_step(
-                    self.params, self.opt_state, self.relay_state,
-                    self.pending, self.batches, self.data_x, self.data_y,
-                    ids, relay_ks, upd_ks, upl_ks, mask,
-                    jnp.asarray(delays_np, jnp.int32),
-                    jnp.asarray(r, jnp.int32), *lag))
-            if self._telem:
-                *out, telem = out
-            if self._lagged:
-                (self.params, self.opt_state, self.relay_state,
-                 self.pending, self.hist, metrics) = out
-            else:
-                (self.params, self.opt_state, self.relay_state,
-                 self.pending, metrics) = out
+        if self._telem:
+            *out, telem = out
+        if self._async and self._lagged:
+            (self.params, self.opt_state, self.relay_state,
+             self.pending, self.hist, metrics) = out
+        elif self._async:
+            (self.params, self.opt_state, self.relay_state,
+             self.pending, metrics) = out
+        elif self._lagged:
+            (self.params, self.opt_state, self.relay_state, self.hist,
+             metrics) = out
         else:
-            if self._k_active < N:
-                idx_np = present                 # static-k compaction
-                assert idx_np.size == self._k_active, (
-                    "schedule emitted a mask inconsistent with its fixed_k",
-                    idx_np.size, self._k_active)
-            else:
-                idx_np = np.arange(N)
-            idx = jnp.asarray(idx_np, jnp.int32)
-            with self._span("round_step", round=r) as sp:
-                out = sp.block(self._round_step(
-                    self.params, self.opt_state, self.relay_state,
-                    self.batches, self.data_x, self.data_y,
-                    ids, relay_ks, upd_ks, upl_ks, mask, idx, *lag))
-            if self._telem:
-                *out, telem = out
-            if self._lagged:
-                (self.params, self.opt_state, self.relay_state, self.hist,
-                 metrics) = out
-            else:
-                self.params, self.opt_state, self.relay_state, metrics = out
+            self.params, self.opt_state, self.relay_state, metrics = out
 
-        up, down = comm.round_floats(
-            mode, n_present=int(present.size), n_commit=len(commits),
-            n_read=int(present.size) if self._lagged else None,
-            C=ccfg.num_classes,
-            d=ccfg.d_feature, m_up=ccfg.m_up, m_down=ccfg.m_down,
-            model_size=(baselines.num_params(self.client_params(0))
-                        if mode == "fedavg" else 0))
-        self.ledger.log_round(up, down)
-
-        metrics_np = jax.tree.map(np.asarray, metrics)
-        metrics_all = [jax.tree.map(lambda v: float(v[i]), metrics_np)
-                       for i in range(N)]
+        with obs.trace.span("fetch"):
+            metrics_np = jax.tree.map(np.asarray, metrics)
+            if obs.trace.recording():
+                obs.trace.count("host_syncs",
+                                len(jax.tree.leaves(metrics_np)))
+        with obs.trace.span("records"):
+            up, down = comm.round_floats(
+                mode, n_present=int(present.size), n_commit=len(commits),
+                n_read=int(present.size) if self._lagged else None,
+                C=ccfg.num_classes,
+                d=ccfg.d_feature, m_up=ccfg.m_up, m_down=ccfg.m_down,
+                model_size=(baselines.num_params(self.client_params(0))
+                            if mode == "fedavg" else 0))
+            self.ledger.log_round(up, down)
+            metrics_all = [jax.tree.map(lambda v: float(v[i]), metrics_np)
+                           for i in range(N)]
         return self._log_round(present, up, down, metrics_all, commits,
                                telemetry=telem)
 
-    def _run_round_bucketed(self) -> Dict:
+    def _run_round_bucketed(self, r: int) -> Dict:
         """One synchronous round across all buckets: every bucket's step
         reads the SAME round-start relay state (downloads), then the shared
         commit writes all uploads in bucket order and merges once."""
         ccfg, N = self.ccfg, self.n_clients
         mode = ccfg.mode
-        r = len(self.history)
-        # The oracle's key schedule, indexed by ORIGINAL client id and
-        # sliced per bucket — bucketing changes execution grouping, never
-        # which randomness a client consumes.
-        self.key, relay_ks, upd_ks, upl_ks = collab.round_keys(self.key, N)
-        mask_np = np.asarray(self.schedule.mask(r, N), bool)
-        present = np.nonzero(mask_np)[0]
-        delays_np = (self.clock.delays(r, N) if self.clock is not None
-                     else np.zeros((N,), np.int64))
-        commits = self._round_commits(r, mask_np, delays_np)
-        rstate0 = self.relay_state
-        # Download lag: every bucket reads from the SAME shared history
-        # ring, each client indexing its own stale snapshot; delays sliced
-        # per bucket like the keys and the participation mask.
-        dl_np = (np.asarray(self.dl_clock.delays(r, N), np.int64)
-                 if self._lagged else None)
-        pending0 = self.pending if self._async else None
+        with obs.trace.span("inputs"):
+            # The oracle's key schedule, indexed by ORIGINAL client id and
+            # sliced per bucket — bucketing changes execution grouping,
+            # never which randomness a client consumes.
+            self.key, relay_ks, upd_ks, upl_ks = collab.round_keys(self.key,
+                                                                   N)
+            mask_np = np.asarray(self.schedule.mask(r, N), bool)
+            present = np.nonzero(mask_np)[0]
+            delays_np = (self.clock.delays(r, N) if self.clock is not None
+                         else np.zeros((N,), np.int64))
+            commits = self._round_commits(r, mask_np, delays_np)
+            rstate0 = self.relay_state
+            # Download lag: every bucket reads from the SAME shared history
+            # ring, each client indexing its own stale snapshot; delays
+            # sliced per bucket like the keys and the participation mask.
+            dl_np = (np.asarray(self.dl_clock.delays(r, N), np.int64)
+                     if self._lagged else None)
+            pending0 = self.pending if self._async else None
         payloads, metrics_parts = [], []
-        with self._span("bucket_steps", round=r) as sp:
+        with obs.trace.span("bucket_steps"):
             for b in self.buckets:
                 ids_j = jnp.asarray(b.ids, jnp.int32)
                 lag_b = ((jnp.asarray(dl_np[b.ids], jnp.int32),)
@@ -1084,10 +1093,9 @@ class VectorizedCollabTrainer:
                     jnp.asarray(mask_np[b.ids]), *lag_b)
                 metrics_parts.append(metrics)
                 payloads.append(payload)
-            sp.block(metrics_parts)
 
         hist_lag = (self.hist,) if self._lagged else ()
-        with self._span("commit", round=r) as sp:
+        with obs.trace.span("commit"):
             if self._async:
                 # The shared commit runs EVERY round: pending uploads can
                 # be due even when nobody trains (and it no-ops when the
@@ -1114,7 +1122,6 @@ class VectorizedCollabTrainer:
             elif self._lagged:
                 # relay untouched this round, but the ring still advances
                 self.hist = self._hist_push(self.hist, rstate0)
-            sp.block(self.relay_state)
 
         telem = None
         if self._telem:
@@ -1137,49 +1144,53 @@ class VectorizedCollabTrainer:
                 rstate0, self.relay_state, jnp.asarray(mask_np),
                 mask_parts, loss_parts, gnorm_parts, *rest)
 
-        up, down = comm.round_floats(
-            mode, n_present=int(present.size), n_commit=len(commits),
-            n_read=int(present.size) if self._lagged else None,
-            C=ccfg.num_classes,
-            d=ccfg.d_feature, m_up=ccfg.m_up, m_down=ccfg.m_down)
-        self.ledger.log_round(up, down)
-
-        metrics_all: List[Dict] = [None] * N
-        for b, metrics in zip(self.buckets, metrics_parts):
-            m_np = jax.tree.map(np.asarray, metrics)
-            for j, i in enumerate(b.ids):
-                metrics_all[int(i)] = jax.tree.map(lambda v: float(v[j]),
-                                                   m_np)
+        with obs.trace.span("fetch"):
+            parts_np = [jax.tree.map(np.asarray, m) for m in metrics_parts]
+            if obs.trace.recording():
+                obs.trace.count("host_syncs", len(jax.tree.leaves(parts_np)))
+        with obs.trace.span("records"):
+            up, down = comm.round_floats(
+                mode, n_present=int(present.size), n_commit=len(commits),
+                n_read=int(present.size) if self._lagged else None,
+                C=ccfg.num_classes,
+                d=ccfg.d_feature, m_up=ccfg.m_up, m_down=ccfg.m_down)
+            self.ledger.log_round(up, down)
+            metrics_all: List[Dict] = [None] * N
+            for b, m_np in zip(self.buckets, parts_np):
+                for j, i in enumerate(b.ids):
+                    metrics_all[int(i)] = jax.tree.map(
+                        lambda v: float(v[j]), m_np)
         return self._log_round(present, up, down, metrics_all, commits,
                                telemetry=telem)
 
     def _log_round(self, present, up, down, metrics_all, commits,
                    telemetry=None) -> Dict:
-        with self._span("eval"):
+        with obs.trace.span("eval"):
             accs = self.evaluate_all()
-        rec = {"round": len(self.history) + 1,
-               "acc_mean": float(np.mean(accs)),
-               "acc_std": float(np.std(accs)),
-               "accs": accs,
-               "metrics": metrics_all,
-               "participants": present.tolist(),
-               "commits": [[b, c] for b, c in commits],
-               "comm_up": up, "comm_down": down}
-        if telemetry is not None:
-            rec["telemetry"] = obs.to_record(telemetry)
-        self.history.append(rec)
-        if self._sink is not None:
-            self._sink.write(rec)
-        if self._tracer is not None and self.telemetry.trace:
-            self._tracer.write()
+        with obs.trace.span("log"):
+            rec = {"round": len(self.history) + 1,
+                   "acc_mean": float(np.mean(accs)),
+                   "acc_std": float(np.std(accs)),
+                   "accs": accs,
+                   "metrics": metrics_all,
+                   "participants": present.tolist(),
+                   "commits": [[b, c] for b, c in commits],
+                   "comm_up": up, "comm_down": down}
+            if telemetry is not None:
+                rec["telemetry"] = obs.to_record(telemetry)
+            self.history.append(rec)
+            if self._sink is not None:
+                self._sink.write(rec)
         return rec
 
     def run(self, rounds: int, log_every: int = 0) -> List[Dict]:
-        for r in range(rounds):
-            rec = self.run_round()
-            if log_every and (r + 1) % log_every == 0:
-                print(f"  round {rec['round']:3d} acc {rec['acc_mean']:.4f}"
-                      f" ±{rec['acc_std']:.4f}")
+        with obs.trace.record(self.telemetry.trace if self.telemetry
+                              else None):
+            for r in range(rounds):
+                rec = self.run_round()
+                if log_every and (r + 1) % log_every == 0:
+                    print(f"  round {rec['round']:3d} acc "
+                          f"{rec['acc_mean']:.4f} ±{rec['acc_std']:.4f}")
         return self.history
 
     # ------------------------------------------------------------------
@@ -1193,7 +1204,9 @@ class VectorizedCollabTrainer:
             for i in range(0, n, batch):         # one sync per stack, not
                 correct = correct + fn(          # one per chunk
                     P, self.test_x[i:i + batch], self.test_y[i:i + batch])
-            return np.asarray(correct)
+            with obs.trace.span("eval_fetch"):
+                obs.trace.count("host_syncs")
+                return np.asarray(correct)
 
         if not self.hetero:
             return (stack_hits(self._eval_hits, self.params) / n).tolist()

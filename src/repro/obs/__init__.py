@@ -7,16 +7,22 @@ Three tiers, one opt-in surface:
      diversity, staleness and commit-lag histograms, pending depth,
      prototype drift, per-bucket loss/grad-norm), REPLICATED on a mesh
      and oracle-checked bit-for-bit between engines;
-  2. `repro.obs.trace` — a `TraceRecorder` wrapping round phases in
-     jax.profiler annotations and emitting Chrome trace-event JSON
-     (open in Perfetto), with opt-in `profile=True` barriers;
+  2. `repro.obs.trace` — one process-wide store of host spans and
+     counters at the round's phase boundaries, off by default: each span
+     is also a jax.profiler annotation, stamped on the clock the device
+     trace uses, and a recording is written once as Chrome trace-event
+     JSON (open in Perfetto);
   3. `repro.obs.sink` / `repro.obs.report` — a JSONL per-round writer and
      the `python -m repro.obs.report` CLI that renders a run summary.
 
 Engines take `telemetry=` (True for in-jit metrics only, or a
-`TelemetryConfig` to add sinks/tracing); the default None keeps every
-round step's traced program byte-identical to a telemetry-free build —
-free when off, and the CI `telemetry` gate bounds the cost when on.
+`TelemetryConfig` to add the sink or a span recording); the default None
+keeps every round step's traced program byte-identical to a
+telemetry-free build. The span store is process-wide: `obs.trace.start()`
+turns it on for every engine in the process (the chip benchmark does so
+from its own files), and no jitted program changes with it on or off. An
+engine's `run()` with `TelemetryConfig.trace` records over that call alone
+(`obs.trace.record`) and stops again the recording it started.
 """
 from __future__ import annotations
 
@@ -30,7 +36,7 @@ from repro.obs import metrics, sink, trace  # noqa: F401  (re-exported tiers)
 from repro.obs.metrics import (  # noqa: F401
     STALE_BINS, RoundTelemetry, round_telemetry, shard_summary, to_record)
 from repro.obs.sink import JsonlWriter, read_jsonl  # noqa: F401
-from repro.obs.trace import NULL_SPAN, TraceRecorder, null_span  # noqa: F401
+from repro.obs.trace import NULL_SPAN, TraceRecorder  # noqa: F401
 
 
 @dataclass(frozen=True)
@@ -39,15 +45,12 @@ class TelemetryConfig:
 
     metrics: compute the in-jit RoundTelemetry each round (adds a
       `telemetry` entry to every round record). jsonl: stream each round
-      record to this JSONL path. trace: write phase spans to this Chrome
-      trace-event JSON path (rewritten every round). profile: make each
-      span block_until_ready on its phase's outputs — honest device-time
-      attribution at the cost of pipelining (implies span recording even
-      without a trace path, for programmatic access via the recorder)."""
+      record to this JSONL path. trace: record the process-wide span
+      store over each `run()` (`obs.trace.record`) and write the recording
+      to this Chrome trace-event JSON path once, at its end."""
     metrics: bool = True
     jsonl: Optional[str] = None
     trace: Optional[str] = None
-    profile: bool = False
 
 
 def resolve(telemetry) -> Optional[TelemetryConfig]:

@@ -186,25 +186,33 @@ def test_report_degrades_without_telemetry(tmp_path):
     assert "commit-lag histogram" not in out
 
 
-def test_trace_chrome_json(tmp_path):
-    """The recorder emits valid Chrome trace-event JSON (Perfetto's
-    "Open trace file" format): complete "X" events with µs timestamps,
-    covering the engine's round phases."""
+def test_trace_chrome_json(tmp_path, monkeypatch):
+    """The span store's recording, written once at the end of `run()`, is
+    valid Chrome trace-event JSON (Perfetto's "Open trace file" format):
+    complete "X" events with µs timestamps, covering each engine's round
+    phases under a `run_round` span."""
+    writes = []
+    write = obs.TraceRecorder.write
+    monkeypatch.setattr(obs.TraceRecorder, "write",
+                        lambda rec, p: writes.append(p) or write(rec, p))
     path = tmp_path / "trace.json"
-    cfg = obs.TelemetryConfig(trace=str(path), profile=True)
-    seq = _build("seq", clock="lognormal:2", telemetry=cfg)
+    seq = _build("seq", clock="lognormal:2",
+                 telemetry=obs.TelemetryConfig(trace=str(path)))
     seq.run(2)
-    doc = json.loads(path.read_text())
-    events = doc["traceEvents"]
+    events = json.loads(path.read_text())["traceEvents"]
     assert events
     names = {e["name"] for e in events}
-    assert {"teacher_read", "update", "upload", "commit",
+    assert {"run_round", "teacher_read", "update", "upload", "commit",
             "eval"} <= names
     for e in events:
         assert e["ph"] == "X" and e["dur"] >= 0 and "ts" in e
+        assert e["args"]["round"] in (0, 1)
 
     vpath = tmp_path / "vtrace.json"
     vec = _build("vec", telemetry=obs.TelemetryConfig(trace=str(vpath)))
     vec.run(2)
-    vnames = {e["name"] for e in json.loads(vpath.read_text())["traceEvents"]}
-    assert {"round_step", "eval"} <= vnames
+    assert writes == [str(path), str(vpath)]  # once a run, not every round
+    vevents = json.loads(vpath.read_text())["traceEvents"]
+    assert {"run_round", "inputs", "round_step", "fetch", "records",
+            "eval", "eval_fetch", "log"} <= {e["name"] for e in vevents}
+    assert {e["args"]["round"] for e in vevents} == {0, 1}
