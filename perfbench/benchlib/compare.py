@@ -10,9 +10,9 @@ produced and what the plain reference produced from the same seed.
 - `quiet_leaves(ref_grad_norms)`: leaves whose reference gradient is under
   a thousandth of the median leaf's. Under Adam such leaves move by
   round-off alone, so their change is not compared.
-- `quant_fp8(x)`, `fp8_round(x)`, `bf16_round(x)`: the roundings the
-  control computes and stores in, one precision below what a configuration
-  states.
+- `round_to(dtype)`, `e4m3_round(x)`, `quant_e4m3(x)`: the roundings a
+  reference stores its parameters in and a control computes in, one
+  precision below what a configuration states.
 """
 from __future__ import annotations
 
@@ -44,40 +44,51 @@ def worst(gaps: Dict[str, float]) -> float:
     return max(gaps.values()) if gaps else 0.0
 
 
-def median(gaps: Dict[str, float]) -> float:
-    return float(np.median(list(gaps.values()))) if gaps else 0.0
+# By `lax.reduce_precision` and plain arithmetic, which the TPU's compiler
+# keeps: inside a large jitted step it may keep a float32 -> bfloat16 ->
+# float32 (or float8) `astype` round trip at float32 as excess precision,
+# and on the chip it did so for the parameters a reference stores.
+E4M3_MAX = 448.0              # largest finite float8_e4m3fn value
+E4M3_MIN_NORMAL = 2.0 ** -6
+E4M3_SUBNORMAL = 2.0 ** -9    # the spacing of its subnormals
 
 
-def _fp8(x):
+def round_to(dtype):
+    """-> a function rounding values to `dtype`'s exponent and mantissa
+    bits, kept in float32."""
     import jax.numpy as jnp
+    from jax import lax
+    fi = jnp.finfo(dtype)
+    return lambda x: lax.reduce_precision(
+        x.astype(jnp.float32), exponent_bits=fi.nexp, mantissa_bits=fi.nmant)
+
+
+def e4m3_round(x):
+    """`x` rounded to float8 e4m3fn with one scale per tensor (max |x| maps
+    to the format's largest finite value, 448), subnormals included, and
+    kept in float32: what an `astype` round trip gives where it is kept."""
+    import jax.numpy as jnp
+    from jax import lax
     x = x.astype(jnp.float32)
-    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 448.0
-    return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
+    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / E4M3_MAX
+    y = x / s
+    normal = lax.reduce_precision(y, exponent_bits=5, mantissa_bits=3)
+    sub = jnp.round(y / E4M3_SUBNORMAL) * E4M3_SUBNORMAL
+    return jnp.where(jnp.abs(y) < E4M3_MIN_NORMAL, sub, normal) * s
 
 
-def quant_fp8(x):
-    """Round `x` to float8 e4m3 with one scale per tensor (max |x| maps to
-    the format's largest finite value, 448), and back to float32. The
-    gradient that flows back through it is rounded the same way, with a
-    scale of its own, as fp8 training scales each operand."""
+def quant_e4m3(x):
+    """`e4m3_round` for a matmul operand: the gradient that flows back
+    through it is rounded the same way, with a scale of its own, as fp8
+    training scales each operand."""
     import jax
 
     @jax.custom_vjp
     def q(x):
-        return _fp8(x)
+        return e4m3_round(x)
 
-    q.defvjp(lambda x: (_fp8(x), None), lambda _, g: (_fp8(g),))
+    q.defvjp(lambda x: (e4m3_round(x), None), lambda _, g: (e4m3_round(g),))
     return q(x)
-
-
-def fp8_round(x):
-    """`quant_fp8` without a gradient: for stored values."""
-    return _fp8(x)
-
-
-def bf16_round(x):
-    import jax.numpy as jnp
-    return x.astype(jnp.bfloat16).astype(jnp.float32)
 
 
 def identity(x):

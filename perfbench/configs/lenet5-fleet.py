@@ -307,7 +307,8 @@ def control(cfg: dict) -> Reference:
     """The reference one precision below what the configuration states
     (float32 parameters, bfloat16 matmul operands): bfloat16 parameters and
     float8 e4m3 matmul operands, per-tensor scaled."""
-    return Reference(cfg, q=compare.quant_fp8, store=compare.bf16_round)
+    return Reference(cfg, q=compare.quant_e4m3,
+                     store=compare.round_to("bfloat16"))
 
 
 # ---------------------------------------------------------------------------

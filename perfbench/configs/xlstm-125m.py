@@ -11,8 +11,9 @@ step; they compile everything the window uses, and what they produce is
 what `check` compares.
 
 The reference below is a plain jax.numpy implementation of the same
-model and objective in float32 at the highest matmul precision. It imports
-nothing of the program. Its mLSTM is the quadratic parallel form of the
+model and objective in float32 at the highest matmul precision, with its
+parameters stored at the configuration's precision. It imports nothing of
+the program. Its mLSTM is the quadratic parallel form of the
 recurrence, where the program runs a chunked one; its sLSTM is the same
 sequential scan. It runs once the window has closed, layer by layer under
 rematerialisation and over the tokens in blocks, so that it fits.
@@ -21,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import re
 from typing import Dict, List, Tuple
 
 import jax
@@ -35,6 +37,7 @@ LOSS_TERMS = ("ce", "kd", "disc", "total")
 HIGHEST = lax.Precision.HIGHEST
 STREAM_TOKENS = 1 << 17
 LOSS_BLOCK = 2048
+_SEGMENT = re.compile(r"\['segments'\]\[(\d+)\]")
 
 
 # ---------------------------------------------------------------------------
@@ -140,19 +143,21 @@ def train_flops_per_token(cfg: dict, tokens_per_step: int) -> float:
 # ---------------------------------------------------------------------------
 class Reference:
     """The xLSTM LM and its CoRS objective in plain jax.numpy, float32;
-    the parameters are held at the configuration's `dtype` between steps.
+    the parameters are held at the configuration's `dtype` between steps,
+    as the program holds them: a bfloat16 norm scale of 1 does not move by
+    Adam's first steps of the learning rate, 1e-3.
 
     `q` rounds every matmul operand and `store` every parameter between
-    steps (by default: float32 operands at the highest precision, and the
-    configuration's `dtype`; `control` lowers both). `half_batch` leaves
-    out the second half of the sequences and takes the mean over the rest.
+    steps (by default: float32 operands at the highest precision, and
+    `compare.round_to` the configuration's `dtype`; `control` lowers both).
+    `half_batch` leaves out the second half of the sequences and takes the
+    mean over the rest.
     """
 
     def __init__(self, cfg: dict, q=compare.identity, store=None,
                  half_batch: bool = False):
-        dt = jnp.dtype(cfg["dtype"])
         self.cfg, self.q, self.half = cfg, q, half_batch
-        self.store = store or (lambda p: p.astype(dt).astype(jnp.float32))
+        self.store = store or compare.round_to(cfg["dtype"])
         self._step = jax.jit(self._step_fn)
 
     def _mm(self, eq, a, b):
@@ -319,8 +324,11 @@ class Reference:
 def control(cfg: dict) -> Reference:
     """The reference one precision below what the configuration states
     (bfloat16 parameters, activations and matmul operands): float8 e4m3,
-    per-tensor scaled, for the matmul operands and the stored parameters."""
-    return Reference(cfg, q=compare.quant_fp8, store=compare.fp8_round)
+    per-tensor scaled, for the matmul operands and the stored parameters.
+    Its operands do not separate it from the program (the sLSTM's chaos
+    swamps them, `compare_summaries`); its stored parameters do: they
+    change by whole e4m3 steps, which `update_gap` reads."""
+    return Reference(cfg, q=compare.quant_e4m3, store=compare.e4m3_round)
 
 
 # ---------------------------------------------------------------------------
@@ -349,24 +357,56 @@ def summary(losses, g_norm, dp_norm, proto_sum1, proto_cnt) -> dict:
             "proto_cnt": np.asarray(proto_cnt, np.float64)}
 
 
+def above_last_slstm(keys) -> List[str]:
+    """The leaves whose first gradient does not pass back through an
+    sLSTM's recurrence: the blocks after the last sLSTM, the final norm and
+    the LM head."""
+    seg = {k: int(m.group(1)) for k in keys if (m := _SEGMENT.match(k))}
+    last = max((i for k, i in seg.items() if "['slstm']" in k), default=-1)
+    return [k for k in keys
+            if k != "['embed']" and seg.get(k, last + 1) > last]
+
+
 def compare_summaries(prog: dict, ref: dict, limits: dict) -> list:
     """The numbers compared, each with its limit.
 
-    At 2048 tokens the sLSTM's recurrence amplifies rounding-level
-    differences of its input: two float32 implementations of this model
-    agree on the first step's loss to 1e-7 but on the gradient of the
-    blocks below an sLSTM only to ~0.5%, and once Adam's sign-like first
-    steps have moved every weight by the learning rate their later
-    features part. So the losses and feature sums are compared at the
-    first step, and the gradient and the change by the median leaf; the
-    token counts, which no rounding moves, after the last step."""
+    - `loss_gap`: every loss term of each of the CHECK_STEPS steps,
+      relative, the worst.
+    - `grad_gap`: the first gradient by the worst leaf among
+      `above_last_slstm`, its norm gap over the larger of that leaf's
+      reference norm and the median of those leaves'.
+    - `update_gap`: the change after CHECK_STEPS steps by the worst leaf,
+      leaving out the leaves whose reference gradient is nought to rounding
+      (`compare.quiet_leaves`).
+    - `count_mismatch`: the per-class token counts after the last step,
+      which no rounding moves: exact.
+    - `proto_gap`: the per-class feature sums after the first step,
+      relative L2; read, not compared.
+
+    At this init the sLSTM's recurrence over 2048 tokens is chaotic: the
+    gradient reaching the blocks below it is over a thousand times the
+    head's, and any change of rounding decorrelates the features of later
+    tokens. On the chip (12 seeds at the cell's size) the worst leaf of the
+    whole first gradient reads 0.24-1.02 for the program, 0.14-1.03 for
+    this reference with bfloat16 operands and parameters, and 0.35-1.96
+    for the control; the feature sums read 0.57-0.68, 0.54-0.64 and
+    1.01-1.09. The program in float32 at HIGHEST reads 0.007-0.045 and
+    0.0012-0.0017: the program is sound, and these numbers measure the
+    chaos. So the gradient is read above the last sLSTM, where the program
+    reads at most 0.016 and the half-batch fault at least 0.40 (the control
+    reads as the program there), and the feature sums are not compared:
+    the control reads them under twice the program. The control fails
+    `update_gap`, the change after the last step (program 0.020-0.115 over
+    24 seeds)."""
     skip = compare.quiet_leaves(ref["g_norm"])
+    top = above_last_slstm(ref["g_norm"])
     ps, rs = prog["proto_sum1"], ref["proto_sum1"]
     values = {
-        "loss_gap": compare.rel_gap(prog["loss"][0], ref["loss"][0]),
-        "grad_gap": compare.median(compare.norm_gaps(prog["g_norm"],
-                                                     ref["g_norm"])),
-        "update_gap": compare.median(compare.norm_gaps(
+        "loss_gap": compare.rel_gap(prog["loss"], ref["loss"]),
+        "grad_gap": compare.worst(compare.norm_gaps(
+            {k: prog["g_norm"][k] for k in top},
+            {k: ref["g_norm"][k] for k in top})),
+        "update_gap": compare.worst(compare.norm_gaps(
             prog["dp_norm"], ref["dp_norm"], skip)),
         "count_mismatch": float(np.sum(prog["proto_cnt"] != ref["proto_cnt"])),
         "proto_gap": float(np.linalg.norm(ps - rs)

@@ -24,3 +24,31 @@ def test_control_fails_and_reference_passes(cell):
     ref = mod.Reference(cfg).run(data)
     checks = mod.compare_summaries(ref, ref, w["limits"])
     assert compare.all_within(checks)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float8_e4m3fn"])
+def test_roundings_are_the_astype_round_trips(dtype):
+    """Where the compiler keeps an `astype` round trip, as this host's
+    does, the control's roundings give the same values, bit for bit, over
+    the whole range: subnormals, ties and the largest value included."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    rng = np.random.default_rng(5)
+    mag = np.exp(rng.uniform(-40, 40, 1 << 16)) * rng.choice([-1, 1], 1 << 16)
+    x = jnp.asarray(mag, jnp.float32)
+    if dtype == "bfloat16":
+        want = x.astype(jnp.bfloat16).astype(jnp.float32)
+        got = jax.jit(compare.round_to(jnp.bfloat16))(x)
+    else:
+        s = jnp.max(jnp.abs(x)) / compare.E4M3_MAX
+        want = (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
+        # half-way ties between e4m3 values, below and above the subnormals
+        ties = jnp.asarray([2.5, 3.5, 17.0, 19.0, 0.5 ** 9 * 1.5,
+                            0.5 ** 9 * 2.5], jnp.float32) * s
+        x = jnp.concatenate([x, ties])
+        want = jnp.concatenate(
+            [want, (ties / s).astype(jnp.float8_e4m3fn).astype(jnp.float32)
+             * s])
+        got = jax.jit(compare.e4m3_round)(x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -31,3 +31,21 @@ def test_xlstm_forward_at_reduced_size_is_the_hand_count():
     cfg.update(disc_tokens=15, num_negatives=15)
     disc = 2 * (3840 + 3840) + 21600
     assert mod.train_flops_per_token(cfg, 15) == 3 * 4586 + disc / 15
+
+
+def test_xlstm_train_flops_at_full_size_are_the_hand_count():
+    cfg, mod = cells.config("xlstm-125m")
+    # mLSTM, d=768, di=1536, H=4, P=384, Q=256 (causal mean 128.5):
+    #   w_up 4,718,592, q/k/v 14,155,776, gates 24,576, down 2,359,296,
+    #   scores 394,752, values 395,780, chunk states and read-out
+    #   2,365,440                                          -> 24,414,212
+    # sLSTM, P=192: gates 4,718,592, recurrence 1,179,648, up 2,359,296,
+    #   down 1,179,648                                     ->  9,437,184
+    # head 2*768*50304 = 77,266,944; 10 mLSTM and 2 sLSTM blocks
+    fwd = 10 * 24_414_212 + 2 * 9_437_184 + 77_266_944
+    assert mod.forward_flops_per_token(cfg) == fwd == 340_283_432
+    # discriminator over 4 x 2048 tokens, T = 8192, K = 1023, V = 50304:
+    #   2*(2*T*d*V + 2*K*d*V) + 6*T*V*K = 3,953,440,456,704 FLOP a step
+    disc = 2 * (632_970_805_248 + 79_044_083_712) + 2_529_410_678_784
+    assert mod.train_flops_per_token(cfg, 4 * 2048) == 3 * fwd + disc / 8192
+    assert mod.train_flops_per_token(cfg, 4 * 2048) == 1_503_448_008
