@@ -4,7 +4,7 @@ from __future__ import annotations
 from repro.configs import (chatglm3_6b, deepseek_67b, deepseek_v2_lite_16b,
                            granite_moe_1b_a400m, minicpm3_4b, qwen2_vl_7b,
                            tinyllama_1_1b, whisper_small, xlstm_125m,
-                           zamba2_1_2b)
+                           xlstm_7x1_125m, zamba2_1_2b)
 from repro.configs.shapes import SHAPES
 from repro.types import ModelConfig, ShapeConfig
 
@@ -12,7 +12,7 @@ ARCHS = {
     c.CONFIG.name: c.CONFIG
     for c in (chatglm3_6b, deepseek_67b, qwen2_vl_7b, granite_moe_1b_a400m,
               xlstm_125m, tinyllama_1_1b, zamba2_1_2b, deepseek_v2_lite_16b,
-              whisper_small, minicpm3_4b)
+              whisper_small, minicpm3_4b, xlstm_7x1_125m)
 }
 
 

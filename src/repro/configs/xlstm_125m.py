@@ -1,6 +1,9 @@
-"""xLSTM-125M [arXiv:2405.04517] — sLSTM + mLSTM blocks (1 sLSTM per 6),
-4 heads, d_ff=0 (blocks carry their own projections). Attention-free:
-long_500k runs natively (O(1) recurrent state)."""
+"""The repo's compact 12-block xLSTM variant at 125M widths (d_model 768, 4
+heads, 1 sLSTM per 6 blocks, dense q/k/v, RMSNorm, an in-block GLU after
+the sLSTM; 193M parameters): after arXiv:2405.04517 but not the paper's
+model, which is `xlstm_7x1_125m`. d_ff=0 (blocks carry their own
+projections). Attention-free: long_500k runs natively (O(1) recurrent
+state)."""
 from repro.types import ModelConfig
 
 _PATTERN = tuple("slstm" if i % 6 == 3 else "mlstm" for i in range(12))

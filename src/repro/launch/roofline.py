@@ -142,11 +142,8 @@ def active_params(cfg) -> float:
         elif kind == "mamba":
             di, N, H = cfg.d_inner, cfg.ssm_state, cfg.mamba_heads
             total += d * (2 * di + 2 * N + H) + di * d
-        elif kind == "mlstm":
-            di = 2 * d
-            total += d * 2 * di + 3 * di * di + di * d
-        elif kind == "slstm":
-            total += 4 * d * d + 4 * d * (d // cfg.num_heads) + 3 * d * d
+        elif kind in ("mlstm", "slstm"):
+            total += xlstm_matmul_params(cfg, kind)
     if cfg.shared_attn_period:
         total += _attn_params(cfg) + _ffn_active(cfg)
     if cfg.is_encoder_decoder:
@@ -154,6 +151,23 @@ def active_params(cfg) -> float:
             4 * d * cfg.num_heads * cfg.head_dim + 2 * d * cfg.d_ff)
         total += cfg.num_layers * (4 * d * cfg.num_heads * cfg.head_dim)
     return total
+
+
+def xlstm_matmul_params(cfg, kind: str) -> float:
+    """Weights of one xLSTM block that multiply every token (2 FLOPs per
+    token each, forward): projections, block-diagonal q/k/v at their block
+    size, gate and recurrent kernels, the published sLSTM's feed-forward."""
+    d, H = cfg.d_model, cfg.num_heads
+    di, P = 2 * d, d // cfg.num_heads
+    if cfg.xlstm_form != "published":
+        if kind == "mlstm":
+            return d * 2 * di + 3 * di * di + di * d
+        return 4 * d * d + 4 * d * P + 3 * d * d
+    if kind == "mlstm":
+        # q/k/v block-diagonal in blocks of 4 (`nn.xlstm.QKV_BLOCK`)
+        return d * 2 * di + 3 * di * 4 + 3 * di * 2 * H + di * d
+    ffn = 3 if cfg.mlp_kind in ("swiglu", "geglu") else 2
+    return 4 * d * P + 4 * d * P + ffn * d * cfg.d_ff
 
 
 def _attn_params(cfg) -> float:
@@ -176,7 +190,8 @@ def _ffn_active(cfg) -> float:
     if cfg.num_experts:
         k = cfg.experts_per_token + cfg.num_shared_experts
         return 3.0 * d * cfg.moe_d_ff * k + d * cfg.num_experts
-    return 3.0 * d * cfg.d_ff if cfg.mlp_kind == "swiglu" else 2.0 * d * cfg.d_ff
+    gated = cfg.mlp_kind in ("swiglu", "geglu")
+    return 3.0 * d * cfg.d_ff if gated else 2.0 * d * cfg.d_ff
 
 
 # ---------------------------------------------------------------------------

@@ -66,6 +66,10 @@ def make_loss_fn(cfg: ModelConfig, ccfg: CollabConfig, *,
 
     def loss_fn(params, batch, proto_means, key):
         out = _lm_outputs(cfg, params, batch)
+        with jax.named_scope("cors_loss"):
+            return objective(params, batch, proto_means, key, out)
+
+    def objective(params, batch, proto_means, key, out):
         feats, logits = out["features"], out["logits"]
         labels = batch["labels"]
         l_ce = losses.ce_loss(logits, labels)
@@ -103,18 +107,24 @@ def make_train_step(cfg: ModelConfig, ccfg: CollabConfig, *,
     exchanges prototypes once per ROUND, not per step — the step then only
     accumulates local stats and `make_round_sync()` does the merge. The
     default True folds the exchange into every step (worst case; what the
-    naive port of the algorithm to synchronous SPMD would do)."""
+    naive port of the algorithm to synchronous SPMD would do).
+
+    Named scopes for the device trace: the model's blocks (`mlstm`,
+    `slstm`, ...) and LM head (`head`), the objective (`cors_loss`), the
+    optimizer (`adam`) and the per-class statistics (`proto_stats`)."""
     loss_fn = make_loss_fn(cfg, ccfg, disc_tokens=disc_tokens)
     C = cfg.vocab_size
 
     def client_step(params, opt, batch, proto_means, key):
         (_, (metrics, feats, labels)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, batch, proto_means, key)
-        params, opt = adam_update(params, grads, opt, lr=lr)
+        with jax.named_scope("adam"):
+            params, opt = adam_update(params, grads, opt, lr=lr)
         # per-class feature stats of this client's batch (paper's uplink)
-        stats = prototypes.accumulate(
-            prototypes.init_state(C, feats.shape[-1]),
-            feats.reshape(-1, feats.shape[-1]), labels.reshape(-1))
+        with jax.named_scope("proto_stats"):
+            stats = prototypes.accumulate(
+                prototypes.init_state(C, feats.shape[-1]),
+                feats.reshape(-1, feats.shape[-1]), labels.reshape(-1))
         return params, opt, stats, metrics
 
     def train_step(state: TrainState, batch, key, participation=None):
@@ -154,12 +164,13 @@ def make_train_step(cfg: ModelConfig, ccfg: CollabConfig, *,
                                        jax.tree.map(avg, params), params)
         if ccfg.mode in ("cors", "fd") and sync_in_step:
             # the paper's exchange: inter-client merge of per-class stats
-            merged = prototypes.ProtoState(
-                jnp.sum(stats.sum, axis=0), jnp.sum(stats.count, axis=0))
-            decay = ccfg.proto_momentum or 1.0
-            proto = prototypes.ProtoState(
-                decay * state.proto.sum + merged.sum,
-                decay * state.proto.count + merged.count)
+            with jax.named_scope("proto_stats"):
+                merged = prototypes.ProtoState(
+                    jnp.sum(stats.sum, axis=0), jnp.sum(stats.count, axis=0))
+                decay = ccfg.proto_momentum or 1.0
+                proto = prototypes.ProtoState(
+                    decay * state.proto.sum + merged.sum,
+                    decay * state.proto.count + merged.count)
         else:
             proto = state.proto
         if participation is None:

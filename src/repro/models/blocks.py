@@ -41,7 +41,9 @@ def _wrap_remat(fn):
 # ---------------------------------------------------------------------------
 # single-layer init / apply
 # ---------------------------------------------------------------------------
-def init_block(key, cfg, kind: str, dtype):
+def init_block(key, cfg, kind: str, dtype, index: int = 0):
+    """`index`: the block's place in the stack (the published sLSTM's
+    forget-gate bias depends on it)."""
     ks = layers.split(key, 4)
     p: Dict[str, Any] = {"norm1": layers.init_norm(cfg.norm_kind, cfg.d_model, dtype)}
     if kind == "attn":
@@ -62,7 +64,11 @@ def init_block(key, cfg, kind: str, dtype):
     elif kind == "mlstm":
         p["mlstm"] = xlstm.init_mlstm(ks[0], cfg, dtype)
     elif kind == "slstm":
-        p["slstm"] = xlstm.init_slstm(ks[0], cfg, dtype)
+        p["slstm"] = xlstm.init_slstm(ks[0], cfg, dtype, index)
+        if cfg.xlstm_form == "published":
+            p["norm2"] = layers.init_norm(cfg.norm_kind, cfg.d_model, dtype)
+            p["mlp"] = layers.init_mlp(cfg.mlp_kind, ks[1], cfg.d_model,
+                                       cfg.d_ff, dtype)
     else:
         raise ValueError(kind)
     return p
@@ -143,6 +149,9 @@ def apply_block(p, cfg, kind: str, x, positions, *, window: int = 0,
         else:
             y = xlstm.slstm_block(p["slstm"], cfg, h)
         x = x + y
+        if cfg.xlstm_form == "published":
+            h2 = layers.apply_norm(cfg.norm_kind, p["norm2"], x, cfg.norm_eps)
+            x = x + layers.apply_mlp(cfg.mlp_kind, p["mlp"], h2)
     else:
         raise ValueError(kind)
     return x, aux, new_cache
@@ -170,11 +179,14 @@ def init_segments(key, cfg, dtype):
     segs = segments_of(cfg)
     out = []
     keys = layers.split(key, len(segs))
+    first = 0
     for (kind, n), k in zip(segs, keys):
         layer_keys = layers.split(k, n)
-        ps = [init_block(lk, cfg, kind, dtype) for lk in layer_keys]
+        ps = [init_block(lk, cfg, kind, dtype, first + i)
+              for i, lk in enumerate(layer_keys)]
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *ps)
         out.append({"kind": kind, "params": stacked, "n": n})
+        first += n
     return out
 
 

@@ -55,7 +55,8 @@ def _head(params, cfg, features):
     w = params.get("lm_head")
     if w is None:                                   # tied
         w = params["embed"].T
-    return jnp.einsum("bsd,dv->bsv", features, w)
+    with jax.named_scope("head"):
+        return jnp.einsum("bsd,dv->bsv", features, w)
 
 
 def _positions(cfg, batch, B, S, offset=0):
@@ -183,8 +184,11 @@ def init_cache(cfg, batch_size: int, ctx_len: int, *, window: int = 0):
         elif kind == "slstm":
             d = cfg.d_model
             z = jnp.zeros((n, batch_size, d), jnp.float32)
-            caches["segments"].append((z, z, jnp.full((n, batch_size, d), -30.0,
-                                                      jnp.float32), z))
+            state = (z, z, jnp.full((n, batch_size, d), -30.0, jnp.float32), z)
+            if cfg.xlstm_form == "published":
+                state = (jnp.zeros((n, batch_size, cfg.ssm_conv - 1, d), dt),
+                         state)
+            caches["segments"].append(state)
     n_shared = len(shared_points(cfg))
     for _ in range(n_shared):
         c = attn_cache(1)

@@ -53,17 +53,22 @@ def init_layernorm(d: int, dtype):
 
 
 def layernorm(params, x, eps: float = 1e-5):
+    """LayerNorm over the last axis; without a "bias" leaf it has none."""
     dt = x.dtype
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.var(x32, axis=-1, keepdims=True)
     y = (x32 - mu) * jax.lax.rsqrt(var + eps)
-    y = y * params["scale"].astype(jnp.float32) + params["bias"].astype(jnp.float32)
+    y = y * params["scale"].astype(jnp.float32)
+    if "bias" in params:
+        y = y + params["bias"].astype(jnp.float32)
     return y.astype(dt)
 
 
 def init_norm(kind: str, d: int, dtype):
-    return init_rmsnorm(d, dtype) if kind == "rmsnorm" else init_layernorm(d, dtype)
+    if kind in ("rmsnorm", "layernorm_nobias"):     # one scale leaf
+        return init_rmsnorm(d, dtype)
+    return init_layernorm(d, dtype)
 
 
 def apply_norm(kind: str, params, x, eps: float):
@@ -100,10 +105,19 @@ def gelu_mlp(params, x):
     return jnp.einsum("...f,fd->...d", h, params["w_down"]) + params["b_down"]
 
 
+def geglu(params, x):
+    """The gated feed-forward with an exact (erf) GeLU gate, in the swiglu
+    layout: w_down(gelu(x w_gate) * x w_up)."""
+    g = jnp.einsum("...d,df->...f", x, params["w_gate"])
+    u = jnp.einsum("...d,df->...f", x, params["w_up"])
+    return jnp.einsum("...f,fd->...d", jax.nn.gelu(g, approximate=False) * u,
+                      params["w_down"])
+
+
 def init_mlp(kind: str, key, d_model: int, d_ff: int, dtype):
-    return (init_swiglu(key, d_model, d_ff, dtype) if kind == "swiglu"
+    return (init_swiglu(key, d_model, d_ff, dtype) if kind in ("swiglu", "geglu")
             else init_gelu_mlp(key, d_model, d_ff, dtype))
 
 
 def apply_mlp(kind: str, params, x):
-    return swiglu(params, x) if kind == "swiglu" else gelu_mlp(params, x)
+    return {"swiglu": swiglu, "geglu": geglu}.get(kind, gelu_mlp)(params, x)

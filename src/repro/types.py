@@ -54,6 +54,13 @@ class ModelConfig:
     ssm_heads: int = 0               # mamba2 heads; 0 -> d_inner // 64
     shared_attn_period: int = 0      # zamba2: shared attn block every k layers
     ssm_chunk: int = 256             # SSD chunk length
+    # xLSTM block form: "compact" is this repo's 12-block variant (dense
+    # q/k/v, RMSNorm, in-block GLU after the sLSTM); "published" is the
+    # paper's (arXiv:2405.04517): mLSTM gates from concat(q, k, v), a
+    # learnable skip and head-wise norm; sLSTM with a causal conv into its
+    # i/f gates, head-wise gate projections and a separate feed-forward
+    # sub-block (`mlp_kind`, `d_ff`) behind its own pre-norm residual.
+    xlstm_form: str = "compact"
 
     # --- encoder-decoder (whisper) ---
     is_encoder_decoder: bool = False
@@ -62,8 +69,8 @@ class ModelConfig:
 
     # --- misc ---
     norm_eps: float = 1e-5
-    norm_kind: str = "rmsnorm"       # rmsnorm | layernorm
-    mlp_kind: str = "swiglu"         # swiglu | gelu
+    norm_kind: str = "rmsnorm"       # rmsnorm | layernorm | layernorm_nobias
+    mlp_kind: str = "swiglu"         # swiglu | gelu | geglu
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
     input_kind: str = "tokens"       # tokens | embeddings (vlm/audio stubs)

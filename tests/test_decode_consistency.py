@@ -17,7 +17,7 @@ S = 12
 APPEND_ARCHS = ["tinyllama-1.1b", "chatglm3-6b", "deepseek-v2-lite-16b",
                 "minicpm3-4b", "granite-moe-1b-a400m"]
 # pure-state archs: caches are recurrent states, append is native
-STATE_ARCHS = ["xlstm-125m"]
+STATE_ARCHS = ["xlstm-125m", "xlstm-7x1-125m"]
 
 
 @pytest.mark.parametrize("arch", APPEND_ARCHS)

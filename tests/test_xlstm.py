@@ -90,3 +90,82 @@ def test_slstm_decode_continues_prefill():
     y_dec, _ = xlstm.slstm_block(p, cfg, x[:, 8:9], cache=state, decode=True)
     np.testing.assert_allclose(y_dec[:, 0], y_full[:, 8], atol=1e-4,
                                rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# the published blocks (xlstm-7x1-125m)
+# ---------------------------------------------------------------------------
+def _published(**kw):
+    return get_arch("xlstm-7x1-125m").reduced(num_layers=1, d_model=64, **kw)
+
+
+def test_published_mlstm_chunked_form_is_the_recurrence():
+    """The published mLSTM block over a whole sequence, chunked, equals the
+    same block stepped one token at a time through its decode cache."""
+    cfg = _published()
+    p = xlstm.init_mlstm(KEY, cfg, jnp.float32)
+    p["w_gates"] = jax.random.normal(KEY, p["w_gates"].shape) * 0.1
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model))
+    y_full = xlstm.mlstm_block(p, cfg, x)
+    H, di = cfg.num_heads, 2 * cfg.d_model
+    cache = (jnp.zeros((2, cfg.ssm_conv - 1, di)),
+             jnp.zeros((2, H, di // H + 1, di // H)))
+    ys = []
+    for t in range(x.shape[1]):
+        y, cache = xlstm.mlstm_block(p, cfg, x[:, t:t + 1], cache=cache,
+                                     decode=True)
+        ys.append(y[:, 0])
+    np.testing.assert_allclose(np.stack(ys, 1), y_full, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("kind", ["mlstm", "slstm"])
+def test_published_block_decode_continues_prefill(kind):
+    cfg = _published()
+    init = xlstm.init_mlstm if kind == "mlstm" else xlstm.init_slstm
+    block = xlstm.mlstm_block if kind == "mlstm" else xlstm.slstm_block
+    p = init(KEY, cfg, jnp.float32)
+    if kind == "slstm":     # published init: recurrent kernels start at 0
+        p["r_gates"] = jax.random.normal(KEY, p["r_gates"].shape) * 0.1
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 9, cfg.d_model))
+    y_full = block(p, cfg, x)
+    _, cache = block(p, cfg, x[:, :8], return_cache=True)
+    y_dec, _ = block(p, cfg, x[:, 8:9], cache=cache, decode=True)
+    np.testing.assert_allclose(y_dec[:, 0], y_full[:, 8], atol=1e-4,
+                               rtol=1e-4)
+
+
+def test_published_parameter_count_is_the_hand_count():
+    """3,605,768 per mLSTM block (up 768x3072, q/k/v 3x384x4x4, conv 4x1536
+    + 1536, gates 4608x8 + 8, head-wise norm 1536, skip 1536, down
+    1536x768, pre-norm 768) and 3,548,160 per sLSTM block (conv 4x768 +
+    768, gates and recurrent kernels 2x4x4x192x192, biases 4x768, norm
+    768, pre-norms 2x768, feed-forward 3x768x1024), 21 and 3 of them, plus
+    the untied embedding and head 2x50304x768 and the final norm."""
+    from repro.launch import roofline
+    from repro.models import lm
+    cfg = get_arch("xlstm-7x1-125m")
+    shapes = jax.eval_shape(lambda k: lm.init_lm(k, cfg), KEY)
+    n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
+    assert n == 21 * 3_605_768 + 3 * 3_548_160 + 77_266_944 + 768
+    assert 163.6e6 <= n <= 163.8e6
+    assert [i for i, k in enumerate(cfg.block_pattern) if k == "slstm"] == [
+        3, 11, 19]
+    # the roofline's FLOP terms: the weights that multiply every token
+    assert roofline.xlstm_matmul_params(cfg, "mlstm") == (
+        768 * 3072 + 3 * 1536 * 4 + 4608 * 8 + 1536 * 768)
+    assert roofline.xlstm_matmul_params(cfg, "slstm") == (
+        2 * 4 * 768 * 192 + 3 * 768 * 1024)
+
+
+def test_published_gate_bias_init():
+    cfg = get_arch("xlstm-7x1-125m")
+    pm = xlstm.init_mlstm(KEY, cfg, jnp.float32)
+    np.testing.assert_allclose(pm["b_gates"][4:], [3.0, 4.0, 5.0, 6.0])
+    assert np.all(np.asarray(pm["w_gates"]) == 0)
+    ps = xlstm.init_slstm(KEY, cfg, jnp.float32, index=11)
+    f = np.asarray(ps["b_gates"]).reshape(4, 4, 192)
+    j = np.arange(192) / 191
+    want = -(-5.0 + 12.0 * j ** (0.3 + 1.3 * 11 / 23))
+    np.testing.assert_allclose(f[1], np.broadcast_to(want, (4, 192)),
+                               rtol=1e-5, atol=1e-6)
+    assert np.all(f[[0, 2, 3]] == 0) and np.all(np.asarray(ps["r_gates"]) == 0)
