@@ -48,3 +48,10 @@ def disc_loss(student_logits, teacher_probs, labels, valid=None):
     v = jnp.ones((M,), jnp.float32) if valid is None else valid.astype(jnp.float32)
     per_pair = -(pos * jnp.log(h) + (1.0 - pos) * jnp.log1p(-h)) * v[None, :]
     return jnp.sum(per_pair, axis=-1)
+
+
+def blockdiag(x, w):
+    """x (..., nb*bs) through the block-diagonal w (nb, bs, bs)."""
+    nb, bs, _ = w.shape
+    y = jnp.einsum("...np,npq->...nq", x.reshape(x.shape[:-1] + (nb, bs)), w)
+    return y.reshape(x.shape)

@@ -24,6 +24,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
 from repro.nn import layers
 
 QKV_BLOCK = 4   # the published mLSTM's q/k/v block-diagonal block size
@@ -83,13 +84,6 @@ def _init_mlstm_published(key, cfg, dtype):
     }
 
 
-def _blockdiag(x, w):
-    """x (..., nb*bs) through the block-diagonal w (nb, bs, bs)."""
-    nb, bs, _ = w.shape
-    y = jnp.einsum("...np,npq->...nq", x.reshape(x.shape[:-1] + (nb, bs)), w)
-    return y.reshape(x.shape)
-
-
 def headwise_norm(scale, y, eps: float):
     """LayerNorm without bias over each head's features: y (B,S,H,P) ->
     (B,S,H*P) float32, scaled by `scale` (H*P,)."""
@@ -121,9 +115,8 @@ def _mlstm_published(p, cfg, x, *, return_cache: bool, cache, decode: bool):
     xm, z = up[..., :di], up[..., di:]
     conv, new_conv_state = _causal_conv(p, xm, cache[0] if decode else None,
                                         decode)
-    q = _blockdiag(conv, p["wq"])
-    k = _blockdiag(conv, p["wk"])
-    v = _blockdiag(xm, p["wv"])
+    q, k = ops.blockdiag(conv, p["wq"], p["wk"])
+    (v,) = ops.blockdiag(xm, p["wv"])
     gates = (jnp.einsum("bse,eh->bsh", jnp.concatenate([q, k, v], axis=-1),
                         p["w_gates"]).astype(jnp.float32)
              + p["b_gates"].astype(jnp.float32))

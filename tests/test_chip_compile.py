@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import relay as relay_lib
 from repro.core import client as client_lib, vec_collab
+from repro.kernels import blockdiag as bd
 from repro.kernels import disc_loss as dl
 from repro.kernels import flash_attention as fa
 from repro.kernels import proto_accum as pa
@@ -100,6 +101,25 @@ def test_flash_attention_compiles(one_chip):
     kv = _sds(one_chip, (1, 4096, 8, 128), jnp.bfloat16)
     compiled = jax.jit(fa.flash_attention).lower(q, kv, kv).compile()
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("m", [1, 2])
+def test_blockdiag_compiles(one_chip, m):
+    """lm-xlstm125m-silo's q/k/v projections, 16 x 2048 rows of 1536, v
+    alone and q and k fused: forward and both gradients, as kernels with
+    no activation-sized copy around them."""
+    n, di = 16 * 2048, 1536
+
+    def loss(x, ws):
+        ys = bd.project(x, bd.tiles(ws), False)
+        return sum(jnp.sum(y.astype(jnp.float32)) for y in ys)
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        _sds(one_chip, (n, di), jnp.bfloat16),
+        _sds(one_chip, (m, di // 4, 4, 4), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    _assert_kernel(compiled)
+    assert not re.search(rf"= \S+\[{n},{di}\]\S* copy\(", text)
 
 
 def _compile_bucket_update_step(sharding, n_clients=32):

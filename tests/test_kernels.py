@@ -4,6 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
+from repro.kernels import blockdiag as bd
 from repro.kernels import disc_loss as dl
 from repro.kernels import flash_attention as fa
 from repro.kernels import proto_accum as pa
@@ -44,6 +46,69 @@ def test_proto_accum(n, d, C, dtype):
     tol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(s, rs, atol=tol, rtol=tol)
     np.testing.assert_allclose(c, rc, atol=1e-6)
+
+
+@pytest.mark.parametrize("di,rows", [(128, 512), (128, 1536), (1536, 512),
+                                     (1536, 1024)])
+@pytest.mark.parametrize("m", [1, 2])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_blockdiag(di, rows, m, dtype):
+    """The kernel's values, dx and dW against the einsum, for one
+    projection and for q and k fused (against two separate einsums).
+    Random blocks, so a transposed block fails. float32: tight; bfloat16:
+    within one rounding of the float32-accumulated einsum of the same
+    bf16 inputs, which the kernel rounds once."""
+    from repro.kernels import ops
+    ks = jax.random.split(KEY, 2 + 2 * m)
+    x = jax.random.normal(ks[0], (2, rows // 2, di), dtype)
+    ws = [jax.random.normal(k, (di // 4, 4, 4), dtype) for k in ks[1:1 + m]]
+    gs = [jax.random.normal(k, x.shape, dtype) for k in ks[1 + m:]]
+
+    def loss(project, x, ws):
+        return sum(jnp.vdot(y.astype(jnp.float32), g.astype(jnp.float32))
+                   for y, g in zip(project(x, ws), gs))
+
+    kernel = lambda x, ws: ops.blockdiag(x, *ws, interpret=True)
+    oracle = lambda x, ws: [ref.blockdiag(x, w) for w in ws]
+    f32 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float32), t)
+    got = (kernel(x, ws), jax.grad(loss, (1, 2))(kernel, x, ws))
+    want = (oracle(*f32((x, ws))), jax.grad(loss, (1, 2))(oracle,
+                                                           *f32((x, ws))))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        scale = np.max(np.abs(b))
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=2e-6 * scale)
+        else:
+            np.testing.assert_allclose(a, b, rtol=2 ** -8, atol=1e-6 * scale)
+
+
+def test_blockdiag_dispatch():
+    """The kernel where its shapes fit, the einsum for one token or a width
+    off the 128 lanes; on the CPU `ops.blockdiag` is the einsum, and the
+    span store counts the path each projection took."""
+    from repro.kernels import ops
+    assert bd.supports(16 * 2048, 1536, 4)          # lm-xlstm125m-silo
+    assert not bd.supports(16, 1536, 4)              # decode: one token
+    assert not bd.supports(16 * 2048, 1000, 4)       # di % 128 != 0
+    ks = jax.random.split(KEY, 3)
+    x = jax.random.normal(ks[0], (2, 256, 128))
+    wq, wk = (jax.random.normal(k, (32, 4, 4)) for k in ks[1:])
+    obs.trace.start()
+    try:
+        q, k = ops.blockdiag(x, wq, wk)
+        assert obs.trace.counters() == {"blockdiag_ref": 2}
+        np.testing.assert_array_equal(q, ref.blockdiag(x, wq))
+        np.testing.assert_array_equal(k, ref.blockdiag(x, wk))
+        (v,) = ops.blockdiag(x, wq, interpret=True)
+        assert obs.trace.counters() == {"blockdiag_ref": 2,
+                                        "blockdiag_kernel": 1}
+        np.testing.assert_allclose(v, q, rtol=1e-6, atol=1e-5)
+        ops.blockdiag(x[:, :1], wq, interpret=True)
+        assert obs.trace.counters()["blockdiag_ref"] == 3
+    finally:
+        obs.trace.stop()
 
 
 @pytest.mark.parametrize("B,C,M", [(32, 10, 10), (64, 1000, 10),

@@ -134,6 +134,40 @@ def test_published_block_decode_continues_prefill(kind):
                                rtol=1e-4)
 
 
+def test_published_mlstm_block_kernel_is_the_einsum(monkeypatch):
+    """The published mLSTM block with its q/k/v projections through the
+    block-diagonal kernel (forced, interpret mode) against the einsum
+    block: output and gradient, under jax.checkpoint as the training scan
+    runs it; d_model 64, so di = 128 is one lane tile."""
+    import functools
+
+    from repro import obs
+    from repro.kernels import ops
+    cfg = _published()
+    p = xlstm.init_mlstm(KEY, cfg, jnp.float32)
+    p["w_gates"] = jax.random.normal(KEY, p["w_gates"].shape) * 0.1
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 512, cfg.d_model))
+    g = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+
+    def run(p, x):
+        block = jax.checkpoint(lambda p, x: xlstm.mlstm_block(p, cfg, x))
+        return jax.value_and_grad(
+            lambda p, x: jnp.vdot(block(p, x), g), (0, 1))(p, x)
+
+    want = run(p, x)
+    monkeypatch.setattr(ops, "blockdiag", functools.partial(
+        ops.blockdiag, interpret=True))
+    obs.trace.start()
+    try:
+        got = run(p, x)
+        assert set(obs.trace.counters()) == {"blockdiag_kernel"}
+    finally:
+        obs.trace.stop()
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5,
+                                   atol=1e-5 * np.max(np.abs(b)))
+
+
 def test_published_parameter_count_is_the_hand_count():
     """3,605,768 per mLSTM block (up 768x3072, q/k/v 3x384x4x4, conv 4x1536
     + 1536, gates 4608x8 + 8, head-wise norm 1536, skip 1536, down
