@@ -68,8 +68,8 @@ def make_trainer(mode: str, n_clients: int, *, lambda_kd: float = 10.0,
                  clock=None, download_clock=None, mesh=None, arrivals=None,
                  fleet=None, telemetry=None):
     """Build a trainer without running it. engine: "vec" (default — ALL
-    benchmark fleets go through the vectorized engine, homogeneous ones as
-    one fused round step and mixed ones bucketed; there is no seq
+    benchmark fleets go through the vectorized engine, whose round is one
+    jitted step over the fleet's client buckets; there is no seq
     special-case for heterogeneous specs) or "seq" (the per-client
     Python-loop oracle, any mix). model: "cnn" (paper's LeNet) or "mlp"
     (cheap-compute client, see models/mlp.py). hetero: a `hetero_fleet`
@@ -86,10 +86,11 @@ def make_trainer(mode: str, n_clients: int, *, lambda_kd: float = 10.0,
     streaming-population spec (repro.sim.get_arrivals, e.g.
     "stream:3,2.0,0.2,100000,0") — clients join/leave an unbounded id
     space over `n_clients` SEATS, and participation is owned by the
-    cohort table. fleet: pass a
-    ready-made `repro.types.FleetConfig` instead of the loose
-    policy/participation/clock/download_clock/mesh kwargs (mixing both is
-    an error, mirroring `resolve_fleet`). telemetry: forwarded to the
+    cohort table. The loose kwargs are this helper's own shorthand: they
+    are folded into the `repro.types.FleetConfig` the trainers take. fleet:
+    pass a ready-made FleetConfig instead (mixing it with the loose
+    policy/participation/clock/download_clock/mesh/arrivals kwargs is an
+    error). telemetry: forwarded to the
     trainer (True or a repro.obs.TelemetryConfig; None = off — the
     benchmark default, so timings measure the telemetry-free program; the
     `telemetry` CI gate measures the on/off delta explicitly)."""

@@ -95,16 +95,15 @@ SEQ_MAX = int(os.environ.get("REPRO_SCALE_SEQ_MAX", "64"))
 
 def _block_round_state(trainer):
     """Barrier on the trainer's device-side round outputs: relay state and
-    client params (per bucket for hetero fleets; the oracle's per-client
-    states otherwise). run_round returns after DISPATCH, so a timed loop
+    client params (the vectorized engine's per-bucket stacks; the oracle's
+    per-client states). run_round returns after DISPATCH, so a timed loop
     without this would count Python dispatch and drop the last round's
     in-flight device work."""
     import jax
     targets = []
     if hasattr(trainer, "relay_state"):          # vectorized engine
         targets.append(trainer.relay_state)
-        targets.append([b.params for b in trainer.buckets]
-                       if trainer.hetero else trainer.params)
+        targets.append([b.params for b in trainer.buckets])
     else:                                        # sequential oracle
         targets.append(trainer.server.state)
         targets.append([c.params for c in trainer.clients])

@@ -39,10 +39,10 @@ def bucketize(specs: Sequence[ClientSpec],
     """Group clients into stackable buckets: (spec, client-id list) pairs in
     FIRST-APPEARANCE order, client-id order within a bucket.
 
-    This ordering is load-bearing: it is the order in which the bucketed
-    vectorized engine (core/vec_collab.py) appends each bucket's uploads to
-    the shared relay, and the sequential oracle (core/collab.py) uploads in
-    the same order so the two engines evolve identical ring state. For a
+    This ordering is load-bearing: it is the order in which the vectorized
+    engine's round step (core/vec_collab.py) appends the buckets' uploads
+    to the shared relay, and the sequential oracle (core/collab.py) uploads
+    in the same order so the two engines evolve identical ring state. For a
     homogeneous fleet there is one bucket and the order degenerates to plain
     client-id order — bit-compatible with the pre-bucketing engines.
 

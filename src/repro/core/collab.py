@@ -17,21 +17,22 @@ shared per-round key schedule).
 Upload ordering: uploads happen in BUCKET order (client_lib.bucketize —
 clients grouped by stackable (spec, param-shape) key in first-appearance
 order, client-id order within a bucket), because that is the order in which
-the bucketed engine writes each bucket's observation rows into the shared
-relay ring. For a homogeneous fleet this degenerates to plain client-id
-order, i.e. exactly the pre-bucketing behavior. Downloads are order-free
-(every present client reads the same round-start state) and the per-client
-key schedule is indexed by client id, so ordering changes nothing else.
+the vectorized round step writes the buckets' observation rows into the
+shared relay ring. For a homogeneous fleet this degenerates to plain
+client-id order, i.e. exactly the pre-bucketing behavior. Downloads are
+order-free (every present client reads the same round-start state) and the
+per-client key schedule is indexed by client id, so ordering changes
+nothing else.
 
-Server behavior is pluggable via `policy` (a repro.relay RelayPolicy spec:
-"flat" | "per_class" | "staleness") and `schedule` (a participation
-schedule: "full" | "uniform_k:K" | "cyclic:K" | "bernoulli:P" |
-"adaptive:P[,BOOST]"); absent clients are skipped entirely — no download,
-no update, no upload, no comm billed — which is the reference semantics
-the vectorized engine's masked client axis is tested against
-(tests/test_relay_policies.py).
+Server behavior is pluggable via `FleetConfig.policy` (a repro.relay
+RelayPolicy spec: "flat" | "per_class" | "staleness") and
+`FleetConfig.participation` (a participation schedule: "full" |
+"uniform_k:K" | "cyclic:K" | "bernoulli:P" | "adaptive:P[,BOOST]"); absent
+clients are skipped entirely — no download, no update, no upload, no comm
+billed — which is the reference semantics the vectorized engine's masked
+client axis is tested against (tests/test_relay_policies.py).
 
-Asynchrony: pass `clock` (a repro.sim ClockModel spec, e.g.
+Asynchrony: set `FleetConfig.clock` (a repro.sim ClockModel spec, e.g.
 "lognormal:4") and uploads commit LATE — a round-r upload with commit
 delay d is parked in the event queue and appended in round r+d, in event
 order (birth round, then upload position; see relay/events.py). This
@@ -41,14 +42,14 @@ time, and therefore stays the bit-exact ring/stamp bookkeeping reference
 under any clock model. A client's teachers always come from the committed
 state at its sync (round start) — in-flight uploads are invisible, which
 is exactly what distinguishes the relay from SplitFed's synchronous
-server. `clock=None` (or D_max=0) is today's synchronous behavior,
+server. No clock (or D_max=0) is today's synchronous behavior,
 bit-identical.
 
-Download lag: pass `download_clock` (same `repro.sim` spec machinery,
-independent seed fold) and a client training in round t reads its teachers
-AND global prototypes from a snapshot `d(client, t)` rounds STALER than
-its round-start sync — the state its round-`t − d` self would have read
-fresh, i.e. the post-merge state of round `t − d − 1` (d = 0 is the
+Download lag: set `FleetConfig.download_clock` (same `repro.sim` spec
+machinery, independent seed fold) and a client training in round t reads
+its teachers AND global prototypes from a snapshot `d(client, t)` rounds
+STALER than its round-start sync — the state its round-`t − d` self would
+have read fresh, i.e. the post-merge state of round `t − d − 1` (d = 0 is the
 round-start state itself) — the stale-sync half that the event log's late
 uploads don't model. This trainer keeps the last
 `H_max = d_max + 1` post-merge states in a host-side most-recent-first
@@ -56,7 +57,7 @@ list, the exact replay of the vectorized engine's relay/history.py ring
 (every ring slot starts as the init state, so early deep reads see the
 Algorithm-1 init in both engines). Downlink is billed at READ — the bytes
 cross the wire when the snapshot is served, however stale it is — so the
-ledger is invariant under the delay map. `download_clock=None` (or
+ledger is invariant under the delay map. No download clock (or
 d_max=0, delay 0 everywhere) is today's round-fresh download,
 bit-identical.
 """
@@ -73,7 +74,7 @@ from repro import obs, relay as relay_lib, sim
 from repro.core import baselines, client as client_lib, comm
 from repro.optim import adam_init
 from repro.relay import events
-from repro.types import CollabConfig, TrainConfig, resolve_fleet
+from repro.types import CollabConfig, FleetConfig, TrainConfig
 
 
 def round_keys(key, n: int):
@@ -101,10 +102,8 @@ class CollabTrainer:
                  client_data: Sequence[Tuple[jax.Array, jax.Array]],
                  test_data: Tuple[jax.Array, jax.Array],
                  ccfg: CollabConfig, tcfg: TrainConfig, seed: int = 0,
-                 fleet=None, policy=None, schedule=None, clock=None,
-                 download_clock=None, telemetry=None):
-        fleet = resolve_fleet(fleet, policy=policy, schedule=schedule,
-                              clock=clock, download_clock=download_clock)
+                 fleet: FleetConfig = None, telemetry=None):
+        fleet = fleet if fleet is not None else FleetConfig()
         if fleet.mesh is not None:
             raise ValueError(
                 "the sequential oracle steps clients host-side and holds "
@@ -117,7 +116,7 @@ class CollabTrainer:
                         data_x=x, data_y=y)
             for s, p, (x, y) in zip(specs, params_list, client_data)]
         self.test_x, self.test_y = test_data
-        # Relay-write order shared with the bucketed vectorized engine:
+        # Relay-write order shared with the vectorized engine:
         # bucket by bucket, client-id order within a bucket (identity for
         # homogeneous fleets). See the module docstring.
         buckets = client_lib.bucketize(specs, params_list)

@@ -192,7 +192,7 @@ def make_round_sync(ccfg: CollabConfig):
     round when the step was built with sync_in_step=False.
 
     Accepts one stats pytree per client-architecture BUCKET (each with its
-    own leading client axis, as in core/vec_collab.py's bucketed engine):
+    own leading client axis, as in core/vec_collab.py's client buckets):
     the proto state is the only thing heterogeneous buckets share, so a
     mixed fleet at LM scale is N_buckets `train_step`s + ONE round_sync
     over all their stats. A single homogeneous stack is the 1-bucket case.

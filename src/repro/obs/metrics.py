@@ -208,34 +208,6 @@ def round_telemetry(prev_state, new_state, n_clients: int, *, mask,
         bucket_grad_norm=jnp.stack(bg).astype(jnp.float32))
 
 
-def make_telemetry_fn(n_clients: int, asynchronous: bool = False,
-                      lagged: bool = False):
-    """Jitted round_telemetry for the BUCKETED vectorized engine, which
-    computes telemetry in one extra dispatch after the shared relay commit
-    (its per-bucket steps and the commit are separate jits, so there is no
-    single step to fuse into). Signature varies with the fleet's clocks —
-    trailing args are (pending_pre, pending_post, round_idx, delays) when
-    asynchronous, then (dl,) when download-lagged. One trace per engine."""
-
-    def fn(prev_state, new_state, mask, mask_parts, loss_parts,
-           gnorm_parts, *rest):
-        rest = list(rest)
-        pending_pre = pending = round_idx = delays = dl = None
-        if asynchronous:
-            pending_pre, pending, round_idx, delays = rest[:4]
-            rest = rest[4:]
-        if lagged:
-            dl = rest[0]
-        return round_telemetry(
-            prev_state, new_state, n_clients, mask=mask,
-            loss_parts=loss_parts, gnorm_parts=gnorm_parts,
-            mask_parts=mask_parts, pending=pending,
-            pending_pre=pending_pre, round_idx=round_idx, delays=delays,
-            dl=dl)
-
-    return jax.jit(fn)
-
-
 def make_host_telemetry_fn(n_clients: int):
     """Jitted round_telemetry for the SEQUENTIAL oracle: same relay-state
     reductions over its bit-equal ring, with the event-log quantities the

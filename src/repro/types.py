@@ -8,7 +8,6 @@ input shapes; TrainConfig / CollabConfig parameterize the paper's technique
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from dataclasses import dataclass
 from typing import Any, Tuple
 
@@ -204,10 +203,8 @@ class FleetConfig:
     """Who the fleet is and how it behaves — everything about a client
     population that is NOT a training hyper-parameter: the relay policy,
     the participation schedule, the upload/download clock models and the
-    device mesh. One object accepted by BOTH engines (`fleet=`), replacing
-    the former loose `policy= / schedule= / clock= / download_clock= /
-    mesh=` trainer kwargs (still accepted for one release through a
-    `DeprecationWarning` shim, `resolve_fleet`).
+    device mesh, the streaming arrivals. One object, and the only way to
+    give either engine its fleet (`fleet=`).
 
     Fields hold either spec strings (parsed by the engines through
     `repro.specs.parse_spec` — e.g. policy="staleness:0.5",
@@ -220,32 +217,6 @@ class FleetConfig:
     download_clock: Any = None          # download ClockModel spec | instance
     mesh: Any = None                    # jax Mesh with a client axis, or None
     arrivals: Any = None                # streaming-population spec | instance
-
-
-def resolve_fleet(fleet=None, **legacy) -> FleetConfig:
-    """The one-release deprecation shim for the pre-FleetConfig trainer
-    kwargs: fold non-None legacy kwargs (`policy`, `schedule`, `clock`,
-    `download_clock`, `mesh`) into a FleetConfig, warning once per call
-    site. Mixing `fleet=` with legacy kwargs is an error — two sources of
-    truth for the same field is exactly the bug FleetConfig removes."""
-    used = {k: v for k, v in legacy.items() if v is not None}
-    if not used:
-        return fleet if fleet is not None else FleetConfig()
-    if fleet is not None:
-        raise ValueError(
-            f"pass fleet=FleetConfig(...) OR legacy kwargs, not both; got "
-            f"fleet and {sorted(used)}")
-    warnings.warn(
-        f"repro: trainer kwargs {sorted(used)} are deprecated; pass "
-        "fleet=FleetConfig(policy=..., participation=..., clock=..., "
-        "download_clock=..., mesh=...) instead",
-        DeprecationWarning, stacklevel=3)
-    return FleetConfig(
-        policy=used.get("policy"),
-        participation=used.get("participation", used.get("schedule")),
-        clock=used.get("clock"),
-        download_clock=used.get("download_clock"),
-        mesh=used.get("mesh"))
 
 
 @dataclass(frozen=True)

@@ -1,4 +1,4 @@
-"""Compile the main path's kernels and the fleet's update step for a TPU
+"""Compile the main path's kernels and the fleet's round step for a TPU
 v5e chip that is described, not attached.
 
 The TPU compiler refuses what interpret mode accepts: tiles not aligned to
@@ -15,6 +15,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
@@ -122,17 +123,18 @@ def test_blockdiag_compiles(one_chip, m):
     assert not re.search(rf"= \S+\[{n},{di}\]\S* copy\(", text)
 
 
-def _compile_bucket_update_step(sharding, n_clients=32):
-    """One bucket of n_clients LeNet5 clients (three local batches of 32)
-    against a flat relay: downlink, vmapped local updates and upload
-    payloads."""
+def _compile_round_step(sharding, n_clients=32):
+    """The fleet's round step for n_clients LeNet5 clients (three local
+    batches of 32) against a flat relay, as one bucket at full
+    participation: downlink, vmapped local updates, upload payloads and
+    the relay commit."""
     N, nb, bs = n_clients, 3, 32
     ccfg = CollabConfig(mode="cors", num_classes=10, d_feature=84)
     policy = relay_lib.get_policy("flat")
     spec = client_lib.ClientSpec(apply=lambda p, x: cnn.apply(p, x),
                                  head=lambda p: (p["head_w"], p["head_b"]))
-    step = vec_collab.make_bucket_update_step(spec, ccfg, TrainConfig(),
-                                              policy)
+    step = vec_collab.make_round_step([spec], [np.arange(N)], ccfg,
+                                      TrainConfig(), policy)
 
     def stacked_init():
         p = cnn.init_cnn(jax.random.PRNGKey(0))
@@ -150,12 +152,15 @@ def _compile_bucket_update_step(sharding, n_clients=32):
     ids = _sds(sharding, (N,), jnp.int32)
     keys = _sds(sharding, (N, 2), jnp.uint32)
     mask = _sds(sharding, (N,), jnp.bool_)
-    return step.lower(params, opt, rstate, batches, data_x, data_y, ids,
-                      keys, keys, keys, mask).compile()
+    # idx, delays, round_idx, hist, dl: None for a synchronous, unlagged,
+    # full-participation fleet
+    return step.lower((params,), (opt,), rstate, None, (batches,),
+                      (data_x,), (data_y,), ids, keys, keys, keys, mask,
+                      None, None, None, None, None).compile()
 
 
 def test_fleet_bucket_update_step_compiles(one_chip):
-    mem = _compile_bucket_update_step(one_chip).memory_analysis()
+    mem = _compile_round_step(one_chip).memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
 
 
@@ -181,13 +186,13 @@ def test_fleet_update_conv1_weight_grad_has_no_output_map_window(
     slower on a TPU v5e). Temp memory stays within a quarter of the plain
     convolution's program."""
     N = 256
-    step = _compile_bucket_update_step(one_chip, N)
+    step = _compile_round_step(one_chip, N)
     windows = _conv_windows(step)
     assert not [w for w in windows if w[0].startswith("24x24x")]
     assert [w[1] for w in windows if w[0].startswith("8x8x")] == \
         [sorted((N, 5, 5, 6, 16))]
     monkeypatch.setattr(cnn, "_conv2d", cnn._valid_conv)
-    plain = _compile_bucket_update_step(one_chip, N)
+    plain = _compile_round_step(one_chip, N)
     assert [w for w in _conv_windows(plain) if w[0].startswith("24x24x")]
     assert (step.memory_analysis().temp_size_in_bytes
             <= 1.25 * plain.memory_analysis().temp_size_in_bytes)

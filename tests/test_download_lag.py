@@ -126,7 +126,7 @@ def test_download_lag_hetero_buckets():
 @pytest.mark.parametrize("policy", POLICIES)
 @pytest.mark.parametrize("dl_clock", DL_CLOCKS)
 def test_async_hetero_download_lag_matrix(policy, dl_clock):
-    """The heaviest cross product: bucketed fleets × event-ordered upload
+    """The heaviest cross product: mixed fleets × event-ordered upload
     lag × download lag, per policy × download clock."""
     run_matched(
         _build("seq", policy, dl_clock, clock="lognormal:2", hetero=True),
@@ -198,7 +198,7 @@ def test_download_lag_composes_with_mesh():
 
 def test_download_lag_step_compiles_once():
     """H_max is static, per-round delays are traced: 3 rounds = 1 compile,
-    for both the sync-lagged and the async×lagged fused steps."""
+    for both the sync-lagged and the async×lagged round steps."""
     vec = _build("vec", "per_class", "lognormal:2")
     for _ in range(3):
         vec.run_round()

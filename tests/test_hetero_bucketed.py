@@ -1,14 +1,14 @@
-"""Bucketed heterogeneous engine (core/vec_collab.py) vs the sequential
-oracle.
+"""Mixed-architecture fleets in the vectorized engine (core/vec_collab.py)
+vs the sequential oracle.
 
 The tentpole invariant: for a MIXED-spec fleet (≥2 stackable buckets,
-interleaved client ids) the bucketed vectorized engine and the sequential
+interleaved client ids) the vectorized engine and the sequential
 oracle evolve identical relay ring bookkeeping (exact ptr/owner/valid/age)
 and the same per-client eval metrics, across relay policies × participation
 schedules — because both write uploads in the same bucket order
 (client_lib.bucketize) under the same per-round key schedule. Plus bucket
-construction mechanics and the no-retrace guarantees of the per-bucket
-steps and the shared relay commit.
+construction mechanics and the no-retrace guarantee of the one round step
+over all buckets.
 """
 import jax
 import numpy as np
@@ -146,14 +146,14 @@ def test_hetero_upload_order_is_bucket_order():
 
 
 def test_hetero_steps_compile_once():
-    """Participation must not retrace the per-bucket steps or the shared
-    relay commit: 3 rounds under a varying-k schedule = 1 trace each."""
+    """A mixed fleet's round is ONE compiled step over all its buckets, and
+    participation never retraces it: 3 rounds under a varying-k schedule =
+    1 trace."""
     vec = _build("vec", "per_class", "bernoulli:0.7")
+    assert len(vec.buckets) > 1
     for _ in range(3):
         vec.run_round()
-    for b in vec.buckets:
-        assert b.step._cache_size() == 1
-    assert vec._relay_commit._cache_size() == 1
+    assert vec._round_step._cache_size() == 1
 
 
 def test_hetero_client_params_roundtrip():

@@ -11,7 +11,7 @@ ring), static-k compaction (k not divisible by the device count — GSPMD
 pads the in-jit block) and bucketed heterogeneous fleets (bucket sizes
 not divisible by the device count — those stacks fall back to
 replicated). Exact ring bookkeeping, commit lists and ledgers;
-float-tolerant observations; compile-once on the fused steps.
+float-tolerant observations; compile-once on the round step.
 """
 import os
 import subprocess
@@ -105,6 +105,7 @@ print("STATICK_OK")
 vec = build("vec", mesh=mesh, hetero=True)
 assert vec.hetero and len(vec.buckets) == 2
 run_matched(build("seq", hetero=True), vec, rounds=2)
+assert vec._round_step._cache_size() == 1
 print("HETERO_OK")
 
 # telemetry on the mesh: every RoundTelemetry leaf is declared REPLICATED

@@ -1,15 +1,14 @@
-"""Placement API surface (src/repro/relay/placement.py) + the FleetConfig
-and re-export deprecation shims.
+"""Placement API surface (src/repro/relay/placement.py) + FleetConfig as
+the trainers' one fleet argument.
 
 Pins the contracts the placement redesign introduced: every relay-side
 state kind declares its placement (`out_spec`), `resolve` turns those
 declarations into NamedShardings, `exchange` is a no-op off-mesh, the
-sequential oracle rejects a mesh with an error that says why, and the
-legacy trainer kwargs warn — tier-1 runs with `repro:`-prefixed
-DeprecationWarnings as errors (pyproject.toml), so these pytest.warns
-tests are the ONLY sanctioned callers of the shims. (The PR-6
-`repro.core.server` re-export shim served its one release and is gone;
-importing it is now a plain ModuleNotFoundError.)
+sequential oracle rejects a mesh with an error that says why, and both
+trainers take the fleet as `fleet=FleetConfig(...)` only — the loose
+pre-FleetConfig kwargs and the `repro.core.server` re-export are retired
+shims, so passing the one is a TypeError and importing the other a
+ModuleNotFoundError.
 """
 import importlib
 import warnings
@@ -24,8 +23,7 @@ from repro.core import client as client_lib, collab, vec_collab
 from repro.data import partition, synthetic
 from repro.models import mlp
 from repro.relay import events, history, placement
-from repro.types import (CollabConfig, FleetConfig, TrainConfig,
-                         resolve_fleet)
+from repro.types import CollabConfig, FleetConfig, TrainConfig
 
 SPEC = client_lib.ClientSpec(
     apply=lambda p, x: mlp.apply(p, x),
@@ -116,41 +114,24 @@ def test_placement_round_step_compiles_once():
 
 
 # ---------------------------------------------------------------------------
-# deprecation shims (the only sanctioned callers — see module docstring)
+# retired shims
 # ---------------------------------------------------------------------------
-def test_legacy_trainer_kwargs_warn_and_still_work():
-    args = _fleet_args()
-    with pytest.warns(DeprecationWarning, match="repro:.*deprecated"):
-        old = vec_collab.VectorizedCollabTrainer(
-            *args, seed=0, policy="staleness", schedule="uniform_k:1")
-    new = vec_collab.VectorizedCollabTrainer(
-        *args, seed=0, fleet=FleetConfig(policy="staleness",
-                                         participation="uniform_k:1"))
-    ro, rn = old.run_round(), new.run_round()
-    assert ro["participants"] == rn["participants"]
-    np.testing.assert_array_equal(ro["accs"], rn["accs"])
+_VEC = vec_collab.VectorizedCollabTrainer
+_SEQ = collab.CollabTrainer
 
 
-def test_legacy_kwargs_warn_on_sequential_engine_too():
-    with pytest.warns(DeprecationWarning, match="repro:"):
-        collab.CollabTrainer(*_fleet_args(), policy="flat")
-
-
-def test_mixing_fleet_and_legacy_kwargs_is_an_error():
-    with pytest.raises(ValueError, match="not both"):
-        resolve_fleet(FleetConfig(policy="flat"), clock="lognormal:2")
-    with pytest.raises(ValueError, match="not both"):
-        vec_collab.VectorizedCollabTrainer(
-            *_fleet_args(), seed=0, fleet=FleetConfig(), policy="flat")
-
-
-def test_resolve_fleet_passthrough_and_fold():
-    assert resolve_fleet(None) == FleetConfig()
-    f = FleetConfig(policy="per_class")
-    assert resolve_fleet(f) is f
-    with pytest.warns(DeprecationWarning, match="repro:"):
-        g = resolve_fleet(schedule="uniform_k:2", mesh=None)
-    assert g.participation == "uniform_k:2" and g.mesh is None
+@pytest.mark.parametrize("engine, kwarg, value", [
+    (_VEC, "mesh", None), (_VEC, "policy", "flat"),
+    (_VEC, "schedule", "uniform_k:1"), (_VEC, "clock", "lognormal:2"),
+    (_VEC, "download_clock", "lognormal:2"),
+    (_SEQ, "policy", "flat"), (_SEQ, "schedule", "uniform_k:1"),
+    (_SEQ, "clock", "lognormal:2"), (_SEQ, "download_clock", "lognormal:2"),
+], ids=lambda v: getattr(v, "__name__", v))
+def test_trainers_take_fleet_config_only(engine, kwarg, value):
+    """The pre-FleetConfig kwargs are gone from both engines: a fleet is
+    `fleet=FleetConfig(...)` and nothing else."""
+    with pytest.raises(TypeError, match=kwarg):
+        engine(*_fleet_args(), seed=0, **{kwarg: value})
 
 
 def test_core_server_shim_is_retired():

@@ -34,8 +34,6 @@ SPEC_B = client_lib.ClientSpec(
     head=lambda p: (p["head_w"], p["head_b"]))
 
 FUSED = ["inputs", "round_step", "fetch", "records", "eval", "log"]
-BUCKETED = ["inputs", "bucket_steps", "commit", "fetch", "records", "eval",
-            "log"]
 SPAN_NAMES = {"run_round", "eval_fetch", *FUSED}
 
 
@@ -82,8 +80,8 @@ def test_round_span_tree_and_counters(case):
     leaves = len(recs[0]["metrics"][0])
     for root in roots:
         kids = _children(spans, root)
-        assert [s.name for s in kids] == (BUCKETED if case == "hetero"
-                                          else FUSED)
+        # every fleet, mixed ones included, runs one round step
+        assert [s.name for s in kids] == FUSED
         evals = _children(spans, kids[-2])
         assert [s.name for s in evals] == ["eval_fetch"] * buckets
         tree = [root] + kids + evals
@@ -96,11 +94,12 @@ def test_round_span_tree_and_counters(case):
             assert p.start_ns <= s.start_ns <= s.end_ns <= p.end_ns
         # one pull per metrics leaf and bucket, one per bucket's eval
         assert root.args["host_syncs"] == buckets * (leaves + 1)
-    # round 0 traces each bucket's step and eval once; round 1, same
-    # shapes, traces nothing
-    assert roots[0].args["traces"] == 2 * buckets
+    assert not [s for s in spans if s.name == "bucket_steps"]
+    # round 0 traces the round step once and each bucket's eval once;
+    # round 1, same shapes, traces nothing
+    assert roots[0].args["traces"] == 1 + buckets
     assert "traces" not in roots[1].args
-    assert obs.trace.counters() == {"traces": 2 * buckets,
+    assert obs.trace.counters() == {"traces": 1 + buckets,
                                     "host_syncs": 2 * buckets * (leaves + 1)}
 
 
@@ -128,7 +127,8 @@ def _lowered_texts():
     """Lowered text of a fresh fleet's round step and `jit_hits`, with the
     arguments of its first round."""
     vec = _fleet()
-    step, hits = vec._round_step, vec._eval_hits
+    (bucket,) = vec.buckets
+    step, hits = vec._round_step, bucket.eval_fn
     calls = {}
 
     def spy(name, fn):
@@ -137,7 +137,7 @@ def _lowered_texts():
             return fn(*args)
         return call
 
-    vec._round_step, vec._eval_hits = spy("step", step), spy("hits", hits)
+    vec._round_step, bucket.eval_fn = spy("step", step), spy("hits", hits)
     vec.run_round()
     return [fn.lower(*calls[name]).as_text()
             for name, fn in (("step", step), ("hits", hits))]

@@ -88,8 +88,8 @@ def test_telemetry_full_matrix(policy, clocking):
 
 
 def test_telemetry_hetero_async():
-    """Two spec-buckets + upload lag: the bucketed engine computes
-    telemetry in its own jitted dispatch (no fused round step) and the
+    """Two spec-buckets + upload lag: the round step computes telemetry
+    over both buckets in the same jitted dispatch, and the
     bucket_loss/grad_norm leaves carry one entry per bucket."""
     seq = _build("seq", "staleness", clock="lognormal:2", hetero=True)
     vec = _build("vec", "staleness", clock="lognormal:2", hetero=True)
