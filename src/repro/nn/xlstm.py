@@ -7,8 +7,8 @@ mLSTM recurrence (per head, state C: (P_v, P_k), normalizer n: (P_k,)):
     y_t = (C_t q_t) / max(|n_t · q_t|, 1)
 This is the SSD recurrence with (dt,B,C,x) := (i,k,q,v) and per-head k/q, so
 we run the same chunked block-decomposition (dense MXU matmuls intra-chunk,
-short scan inter-chunk); the normalizer rides along as an appended ones
-column of v. sLSTM's stabilized exponential gating is inherently sequential
+short scan inter-chunk) with a hand-written backward; the normalizer n is
+a float32 state of its own. sLSTM's stabilized exponential gating is inherently sequential
 (running max m_t), so it uses lax.scan over time — faithful to the paper,
 and the reason the xLSTM LMs keep sLSTM layers sparse.
 
@@ -169,46 +169,170 @@ def mlstm_chunked(q, k, v, logf, logi, Q: int, state=None):
     f32 = jnp.float32
     Q = min(Q, S)
     assert S % Q == 0
-    nc = S // Q
-    ones = jnp.ones((B, S, H, 1), f32)
-    va = jnp.concatenate([v.astype(f32), ones], axis=-1)     # (B,S,H,P+1)
-    scale = P ** -0.5
+    with jax.named_scope("mlstm_core"):
+        if state is None:
+            C0 = jnp.zeros((B, H, P, P), f32)
+            n0 = jnp.zeros((B, H, P), f32)
+        else:
+            C0, n0 = state[:, :, :P], state[:, :, P]
+        # (B,S,H,...) -> head-major and chunk-major (nc,B,H,Q,...): the
+        # core's scan takes a chunk a step, the heads a batch axis
+        chunks = lambda x: x.reshape(B, S // Q, Q, H, -1).transpose(1, 0, 3, 2, 4)
+        gate = lambda x: chunks(x.astype(f32)[..., None])[..., 0]
+        y, C, n = _mlstm_core(chunks(q), chunks(k), chunks(v),
+                              jnp.cumsum(gate(logf), axis=-1), gate(logi),
+                              C0, n0)
+        return (y.transpose(1, 0, 3, 2, 4).reshape(B, S, H, P),
+                jnp.concatenate([C, n[:, :, None, :]], axis=2))
 
-    qc = (q.astype(f32) * scale).reshape(B, nc, Q, H, P)
-    kc = k.astype(f32).reshape(B, nc, Q, H, P)
-    vc = va.reshape(B, nc, Q, H, P + 1)
-    ic = logi.astype(f32).reshape(B, nc, Q, H)
-    fc = logf.astype(f32).reshape(B, nc, Q, H)
-    cums = jnp.cumsum(fc, axis=2)
-    total = cums[:, :, -1, :]
 
-    Gm = jnp.einsum("bcihp,bcjhp->bcijh", qc, kc)            # (B,nc,Q,Q,H)
-    Ld = cums[:, :, :, None, :] - cums[:, :, None, :, :] + ic[:, :, None, :, :]
-    causal = jnp.tril(jnp.ones((Q, Q), bool))
-    # Mask before the exp: above the diagonal Ld grows with the chunk
-    # (~0.7 per step at f = sigmoid(0)) and exp overflows past Q ~ 128; a
-    # where() after the exp then sends 0 * inf = NaN into the gradient.
-    W = Gm * jnp.exp(jnp.where(causal[None, None, :, :, None], Ld, -jnp.inf))
-    y_intra = jnp.einsum("bcijh,bcjhp->bcihp", W, vc)
+# The chunkwise mLSTM with a hand-written backward. q, k, v come in
+# (nc,B,H,Q,P): a chunk is one step of a lax.scan, the heads a batch axis
+# of every dot, a row P columns (3 x 128 lanes at P = 384). Per chunk and
+# head, with a the in-chunk cumsum of log f, g = a[-1] and s = P^-1/2:
+#   W_ij = s q_i.k_j exp(a_i - a_j + logi_j) [j <= i]  decay-weighted scores
+#   h_i  = sum_j W_ij v_j + s e^a_i C q_i               numerator
+#   m_i  = sum_j W_ij     + s e^a_i n.q_i               normaliser
+#   y_i  = h_i / max(|m_i|, 1)
+#   C'   = e^g C + sum_j w_j v_j k_j^T,  n' = e^g n + sum_j w_j k_j,
+#   w_j  = exp(g - a_j + logi_j)
+# Dots take q, k, v's own dtype with float32 results; scores, cumsums,
+# exponents, states and the normaliser stay float32. The forward saves the
+# inputs, y, m and each chunk's entry state; the backward, a reverse scan,
+# recomputes the scores chunk by chunk and carries dC, dn back through the
+# chunks.
+def _dot(spec, a, b):
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
 
-    decay_to_end = jnp.exp(total[:, :, None, :] - cums + ic)  # (B,nc,Q,H)
-    Sc = jnp.einsum("bcjh,bcjhp,bcjhn->bchpn", decay_to_end, vc, kc)
 
-    if state is None:
-        state = jnp.zeros((B, H, P + 1, P), f32)
+def _causal_bias(Q):
+    """(Q,Q) float32: 0 where j <= i, -inf above the diagonal."""
+    return jnp.where(jnp.tril(jnp.ones((Q, Q), bool)), 0.0, -jnp.inf)
 
-    def body(C, inp):
-        tot_c, S_c = inp
-        return jnp.exp(tot_c)[:, :, None, None] * C + S_c, C
 
-    C_final, C_enter = jax.lax.scan(
-        body, state, (total.transpose(1, 0, 2), Sc.transpose(1, 0, 2, 3, 4)))
-    C_enter = C_enter.transpose(1, 0, 2, 3, 4)               # (B,nc,H,P+1,P)
-    y_inter = jnp.einsum("bcihn,bcih,bchpn->bcihp",
-                         qc, jnp.exp(cums), C_enter)
-    ya = (y_intra + y_inter).reshape(B, S, H, P + 1)
-    y = ya[..., :P] / jnp.maximum(jnp.abs(ya[..., P:]), 1.0)
-    return y, C_final
+def _scores(q, k, a, li, bias):
+    """One chunk: (s q k^T, exp(a_i - a_j + logi_j) masked to j <= i by
+    `bias`), each (...,Q,Q) f32."""
+    # Mask before the exp: above the diagonal the exponent grows with the
+    # chunk (~0.7 per step at f = sigmoid(0)) and exp overflows past Q ~
+    # 128; a where() after the exp then sends 0 * inf = NaN into the
+    # gradient.
+    ld = a[..., :, None] - a[..., None, :] + li[..., None, :]
+    return (_dot("...ip,...jp->...ij", q, k) * q.shape[-1] ** -0.5,
+            jnp.exp(ld + bias))
+
+
+def _chunk_forward(q, k, v, a, li, C, n, bias):
+    """One chunk: q,k,v (...,Q,P); a, li (...,Q); entry state C (...,P,P),
+    n (...,P). -> (y, m), the exit state."""
+    dt = q.dtype
+    S, D = _scores(q, k, a, li, bias)
+    W = S * D
+    e = jnp.exp(a) * q.shape[-1] ** -0.5
+    h = (_dot("...ij,...jv->...iv", W.astype(dt), v)
+         + e[..., None] * _dot("...ik,...vk->...iv", q, C.astype(dt)))
+    m = (jnp.sum(W, axis=-1)
+         + e * jnp.sum(q.astype(jnp.float32) * n[..., None, :], axis=-1))
+    g = a[..., -1]
+    w = jnp.exp(g[..., None] - a + li)
+    eg = jnp.exp(g)
+    C_out = (eg[..., None, None] * C
+             + _dot("...jv,...jk->...vk", (v * w[..., None]).astype(dt), k))
+    n_out = eg[..., None] * n + jnp.sum(w[..., None] * k, axis=-2)
+    return (h / jnp.maximum(jnp.abs(m), 1.0)[..., None], m), (C_out, n_out)
+
+
+def _chunk_backward(q, k, v, a, li, C, n, y, m, dy, dC, dn, bias):
+    """The VJP of one chunk, given its output y and normaliser m, the
+    cotangents of its output dy and of its exit state dC, dn. -> (dq, dk,
+    dv, da, dli), the cotangents of its entry state."""
+    dt = q.dtype
+    s = q.shape[-1] ** -0.5
+    S, D = _scores(q, k, a, li, bias)
+    W = S * D
+    dh = dy / jnp.maximum(jnp.abs(m), 1.0)[..., None]
+    hh = jnp.sum(dy * y, axis=-1)   # dh.h, as y = h / max(|m|, 1)
+    free = jnp.abs(m) > 1.0         # where the clamp max(|m|, 1) is off
+    dm = jnp.where(free, -hh / m, 0.0)
+    dhl = dh.astype(dt)
+    dW = _dot("...iv,...jv->...ij", dhl, v) + dm[..., None]
+    dS = (dW * D * s).astype(dt)
+    cols = jnp.sum(dW * W, axis=-2)  # column sums of d(exponent)
+    e = jnp.exp(a) * s
+    g = a[..., -1]
+    w = jnp.exp(g[..., None] - a + li)
+    eg = jnp.exp(g)
+    T = _dot("...jk,...vk->...jv", k, dC.astype(dt))       # dC k_j
+    dq = (_dot("...ij,...jk->...ik", dS, k)
+          + e[..., None] * (_dot("...iv,...vk->...ik", dhl, C.astype(dt))
+                            + dm[..., None] * n[..., None, :]))
+    dk = (_dot("...ij,...ik->...jk", dS, q)
+          + w[..., None] * (_dot("...jv,...vk->...jk", v, dC.astype(dt))
+                            + dn[..., None, :]))
+    dv = _dot("...ij,...iv->...jv", W.astype(dt), dhl) + w[..., None] * T
+    dlw = w * (jnp.sum(v * T, axis=-1)
+               + jnp.sum(k * dn[..., None, :], axis=-1))
+    dg = (eg * (jnp.sum(dC * C, axis=(-2, -1)) + jnp.sum(dn * n, axis=-1))
+          + jnp.sum(dlw, axis=-1))
+    # Row i of the decay and the inter-chunk terms scale h_i and m_i alike
+    # by e^a_i: where |m| > 1, y_i does not move with a_i through them.
+    da = jnp.where(free, 0.0, hh) - cols - dlw
+    da = da.at[..., -1].add(dg)
+    dli = cols + dlw
+    dC_in = (eg[..., None, None] * dC
+             + _dot("...iv,...ik->...vk", (dh * e[..., None]).astype(dt), q))
+    dn_in = eg[..., None] * dn + jnp.sum((e * dm)[..., None] * q, axis=-2)
+    return (dq, dk, dv, da, dli), (dC_in, dn_in)
+
+
+def _core_forward(q, k, v, a, li, C, n, save: bool):
+    """-> y (nc,B,H,Q,P) f32 and the exit state (C, n); with `save`, also
+    m and the entry state of every chunk."""
+    bias = _causal_bias(q.shape[-2])
+
+    def body(state, x):
+        (y, m), out = _chunk_forward(*x, *state, bias)
+        return out, ((y, m, state) if save else y)
+
+    return jax.lax.scan(body, (C, n), (q, k, v, a, li))
+
+
+@jax.custom_vjp
+def _mlstm_core(q, k, v, a, li, C, n):
+    """q,k,v (nc,B,H,Q,P); a (in-chunk cumsums of log f), li (nc,B,H,Q)
+    f32; entry state C (B,H,P,P), n (B,H,P) f32. -> y (nc,B,H,Q,P) f32,
+    exit state C, n."""
+    with jax.named_scope("mlstm_core"):
+        (C, n), y = _core_forward(q, k, v, a, li, C, n, save=False)
+        return y, C, n
+
+
+def _mlstm_core_fwd(q, k, v, a, li, C, n):
+    with jax.named_scope("mlstm_core"):
+        (C, n), (y, m, states) = _core_forward(q, k, v, a, li, C, n,
+                                               save=True)
+        return (y, C, n), (q, k, v, a, li, states, y, m)
+
+
+def _mlstm_core_bwd(res, cts):
+    q, k, v, a, li, states, y, m = res
+    dy, dC, dn = cts
+
+    def body(dstate, x):
+        *x, (C, n), yc, mc, dyc = x
+        grads, dstate = _chunk_backward(*x, C, n, yc, mc, dyc, *dstate, bias)
+        dq, dk, dv, da, dli = grads
+        return dstate, (dq.astype(q.dtype), dk.astype(k.dtype),
+                        dv.astype(v.dtype), da, dli)
+
+    with jax.named_scope("mlstm_core"):
+        bias = _causal_bias(q.shape[-2])
+        (dC, dn), grads = jax.lax.scan(
+            body, (dC, dn), (q, k, v, a, li, states, y, m, dy), reverse=True)
+        return (*grads, dC, dn)
+
+
+_mlstm_core.defvjp(_mlstm_core_fwd, _mlstm_core_bwd)
 
 
 def mlstm_block(p, cfg, x, *, return_cache: bool = False, cache=None,

@@ -62,6 +62,112 @@ def test_mlstm_chunked_grad_finite_on_long_chunks():
     assert all(bool(jnp.all(jnp.isfinite(g))) for g in grads)
 
 
+def _autodiff_mlstm_chunked(q, k, v, logf, logi, Q):
+    """The chunked mLSTM as plain jax.numpy under autodiff, with the
+    normaliser as a ones column appended to v: the formulation the custom
+    VJP replaced. -> y (B,S,H,P), C_final (B,H,P+1,P), the normaliser
+    n.q (B,S,H)."""
+    B, S, H, P = q.shape
+    f32 = jnp.float32
+    nc = S // Q
+    va = jnp.concatenate([v.astype(f32), jnp.ones((B, S, H, 1), f32)], -1)
+    qc = (q.astype(f32) * P ** -0.5).reshape(B, nc, Q, H, P)
+    kc = k.astype(f32).reshape(B, nc, Q, H, P)
+    vc = va.reshape(B, nc, Q, H, P + 1)
+    ic = logi.astype(f32).reshape(B, nc, Q, H)
+    cums = jnp.cumsum(logf.astype(f32).reshape(B, nc, Q, H), axis=2)
+    total = cums[:, :, -1, :]
+    Gm = jnp.einsum("bcihp,bcjhp->bcijh", qc, kc)
+    Ld = cums[:, :, :, None, :] - cums[:, :, None, :, :] + ic[:, :, None]
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+    W = Gm * jnp.exp(jnp.where(causal[None, None, :, :, None], Ld, -jnp.inf))
+    y_intra = jnp.einsum("bcijh,bcjhp->bcihp", W, vc)
+    decay_to_end = jnp.exp(total[:, :, None, :] - cums + ic)
+    Sc = jnp.einsum("bcjh,bcjhp,bcjhn->bchpn", decay_to_end, vc, kc)
+
+    def body(C, inp):
+        tot_c, S_c = inp
+        return jnp.exp(tot_c)[:, :, None, None] * C + S_c, C
+
+    C_final, C_enter = jax.lax.scan(
+        body, jnp.zeros((B, H, P + 1, P), f32),
+        (total.transpose(1, 0, 2), Sc.transpose(1, 0, 2, 3, 4)))
+    y_inter = jnp.einsum("bcihn,bcih,bchpn->bcihp", qc, jnp.exp(cums),
+                         C_enter.transpose(1, 0, 2, 3, 4))
+    ya = (y_intra + y_inter).reshape(B, S, H, P + 1)
+    y = ya[..., :P] / jnp.maximum(jnp.abs(ya[..., P:]), 1.0)
+    return y, C_final, ya[..., P]
+
+
+def _mlstm_inputs(key, B, S, H, P, clamped):
+    """Inputs whose normaliser |n.q| stays below 1 everywhere (`clamped`:
+    small q and k) or above 1 everywhere (q and k positive, so that every
+    score is positive and the first already above 1)."""
+    ks = jax.random.split(key, 6)
+    if clamped:
+        q, k = (jax.random.normal(ks[i], (B, S, H, P)) * 0.1 for i in (0, 1))
+    else:
+        q, k = (1.0 + jnp.abs(jax.random.normal(ks[i], (B, S, H, P)))
+                for i in (0, 1))
+    v = jax.random.normal(ks[2], (B, S, H, P))
+    logf = jax.nn.log_sigmoid(jax.random.normal(ks[3], (B, S, H)) + 2.0)
+    logi = 0.5 + jax.random.normal(ks[4], (B, S, H)) * 0.3
+    return q, k, v, logf, logi
+
+
+def _assert_trees_close(got, want):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5,
+                                   atol=2e-5 * float(np.max(np.abs(b))))
+
+
+@pytest.mark.parametrize("Q", [4, 16, 32], ids=["chunk4", "chunk16",
+                                                 "whole"])
+@pytest.mark.parametrize("clamped", [True, False], ids=["clamped", "free"])
+def test_mlstm_chunked_vjp_matches_autodiff(Q, clamped):
+    """The custom VJP against jax.grad of the autodiff formulation, f32 at
+    HIGHEST: y, C_final and the gradients of all five inputs, with the
+    max(|n.q|, 1) clamp active everywhere or nowhere."""
+    B, S, H, P = 2, 32, 2, 8
+    args = _mlstm_inputs(jax.random.PRNGKey(Q), B, S, H, P, clamped)
+    ks = jax.random.split(jax.random.PRNGKey(7), 2)
+    gy = jax.random.normal(ks[0], (B, S, H, P))
+    gC = jax.random.normal(ks[1], (B, H, P + 1, P))
+
+    def loss(f):
+        def run(*a):
+            y, C = f(*a, Q)[:2]
+            return jnp.vdot(y, gy) + jnp.vdot(C, gC)
+        return run
+
+    with jax.default_matmul_precision("highest"):
+        y_ref, C_ref, nq = _autodiff_mlstm_chunked(*args, Q)
+        assert bool(jnp.all(jnp.abs(nq) < 1.0) if clamped
+                    else jnp.all(jnp.abs(nq) > 1.0))
+        y, C = xlstm.mlstm_chunked(*args, Q)
+        want = jax.grad(loss(_autodiff_mlstm_chunked), range(5))(*args)
+        got = jax.grad(loss(xlstm.mlstm_chunked), range(5))(*args)
+    _assert_trees_close((y, C), (y_ref, C_ref))
+    _assert_trees_close(got, want)
+
+
+def test_mlstm_chunked_vjp_under_vmap_of_checkpoint():
+    """As make_train_step runs the blocks: vmapped over clients, each
+    under jax.checkpoint."""
+    N, B, S, H, P, Q = 3, 1, 32, 2, 8, 16
+    keys = jax.random.split(jax.random.PRNGKey(3), N)
+    args = jax.vmap(lambda key: _mlstm_inputs(key, B, S, H, P, False))(keys)
+    g = jax.random.normal(jax.random.PRNGKey(4), (N, B, S, H, P))
+
+    def grads(f):
+        step = jax.vmap(jax.checkpoint(lambda *a: f(*a, Q)[0]))
+        return jax.grad(lambda *a: jnp.vdot(step(*a), g), range(5))(*args)
+
+    with jax.default_matmul_precision("highest"):
+        _assert_trees_close(grads(xlstm.mlstm_chunked),
+                            grads(_autodiff_mlstm_chunked))
+
+
 def test_mlstm_block_decode_continues_prefill():
     cfg = get_arch("xlstm-125m").reduced(num_layers=1, d_model=64)
     p = xlstm.init_mlstm(KEY, cfg, jnp.float32)
@@ -203,3 +309,49 @@ def test_published_gate_bias_init():
     np.testing.assert_allclose(f[1], np.broadcast_to(want, (4, 192)),
                                rtol=1e-5, atol=1e-6)
     assert np.all(f[[0, 2, 3]] == 0) and np.all(np.asarray(ps["r_gates"]) == 0)
+
+
+def _compiled_op_names(fn, *args):
+    """op_name of every instruction of the compiled module, fused ones
+    included: the names a device trace shows."""
+    import re
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return re.findall(r'^\s+(?:ROOT )?%\S+ = .*op_name="([^"]*)"', text,
+                      re.M)
+
+
+def test_published_mlstm_core_ops_carry_their_scopes(monkeypatch):
+    """The gradient of a published mLSTM block under jax.checkpoint, as the
+    training step runs it, compiled: every op of the block carries `mlstm`
+    and every op of the chunkwise core (forward, recompute and backward)
+    carries `mlstm_core`, by the benchmark's scope matcher. The core's ops
+    are found by an extra scope opened around its call."""
+    import importlib.util
+    import pathlib
+    path = (pathlib.Path(__file__).parents[1] / "perfbench" / "benchlib"
+            / "scopes.py")
+    spec = importlib.util.spec_from_file_location("bench_scopes", path)
+    scopes = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(scopes)
+
+    core = xlstm.mlstm_chunked
+
+    def probed(*a, **kw):
+        with jax.named_scope("probe"):
+            return core(*a, **kw)
+
+    monkeypatch.setattr(xlstm, "mlstm_chunked", probed)
+    cfg = _published()
+    p = xlstm.init_mlstm(KEY, cfg, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model))
+    block = jax.checkpoint(lambda p, x: xlstm.mlstm_block(p, cfg, x))
+    names = _compiled_op_names(
+        lambda p, x, g: jax.vjp(block, p, x)[1](g), p, x, x)
+    in_block = [n for n in names if "/checkpoint/" in n]
+    match = lambda scope: (lambda n: scopes._matcher([scope])(
+        type("Op", (), {"scope": n})))
+    assert in_block and all(map(match("mlstm"), in_block))
+    in_core = [n for n in in_block if match("probe")(n)]
+    assert all(map(match("mlstm_core"), in_core))
+    recompute = [n for n in in_core if "rematted_computation" in n]
+    assert len(recompute) > 50 and len(in_core) - len(recompute) > 50
